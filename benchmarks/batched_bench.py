@@ -12,10 +12,6 @@ crossover.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 import time
 from typing import List, Tuple
 
@@ -33,22 +29,16 @@ BENCH_DEGREE = 4
 WEAK_EDGES_PER_DEV = 2048
 WEAK_DEVICE_COUNTS = (1, 2, 4, 8)
 
-_WEAK_SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=%(max_devices)d")
-import json
-import time
-import numpy as np
-from repro.core.distributed_mst import make_flat_mesh
-from repro.core.sharded_mst import sharded_msf
-from repro.graphs.partition_edges import partition_edges
-from repro.graphs.generator import generate_graph
 
-EDGES_PER_DEV = %(edges_per_dev)d
-out = []
-for n_dev in %(device_counts)r:
-    e = EDGES_PER_DEV * n_dev
+def weak_scaling_point(n_dev: int, edges_per_dev: int) -> dict:
+    """One weak-scaling point: a warm ``sharded_msf`` solve of a ~degree-6
+    graph with ``edges_per_dev * n_dev`` edges over an ``n_dev`` mesh
+    (best of 3 after one untimed compile)."""
+    from repro.core.distributed_mst import make_flat_mesh
+    from repro.core.sharded_mst import sharded_msf
+    from repro.graphs.partition_edges import partition_edges
+
+    e = edges_per_dev * n_dev
     v = max(16, e // 3)  # ~degree-6 graphs, growing with the mesh
     g = generate_graph(v, 6, seed=n_dev)
     mesh = make_flat_mesh(n_dev)
@@ -64,48 +54,27 @@ for n_dev in %(device_counts)r:
         t0 = time.perf_counter()
         run()
         best = min(best, time.perf_counter() - t0)
-    out.append({
-        "n_dev": n_dev,
-        "num_edges": g.num_edges,
-        "num_nodes": v,
-        "us": best * 1e6,
-        "edges_per_dev": part.shard_edges,
-        "topology_bytes_per_dev": part.bytes_per_shard,
-    })
-print("RESULT:" + json.dumps(out))
-"""
+    return {"n_dev": n_dev, "num_edges": g.num_edges, "num_nodes": v,
+            "us": best * 1e6, "edges_per_dev": part.shard_edges,
+            "topology_bytes_per_dev": part.bytes_per_shard}
 
 
 def weak_scaling_rows(edges_per_dev: int = WEAK_EDGES_PER_DEV,
                       device_counts=WEAK_DEVICE_COUNTS
                       ) -> List[Tuple[str, float, str]]:
-    """Sharded-engine weak scaling on forced host devices (subprocess).
+    """Sharded-engine weak scaling: mesh sizes swept with a constant
+    per-device edge load, over the chips of a TPU host or forced host
+    devices on a CPU (``mst_figures.run_mesh_sizes``).
 
-    One child process forces ``max(device_counts)`` host devices (the flag
-    must precede jax init), then sweeps mesh sizes with a constant
-    per-device edge load.
     The derived column records the per-device topology footprint — the
     number BENCH_mst.json tracks across PRs to catch replication creeping
     back in.
     """
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    src = os.path.join(repo, "src")
-    prev = env.get("PYTHONPATH")
-    env["PYTHONPATH"] = src + (os.pathsep + prev if prev else "")
-    env["JAX_PLATFORMS"] = "cpu"
-    script = _WEAK_SCRIPT % {"edges_per_dev": edges_per_dev,
-                             "device_counts": tuple(device_counts),
-                             "max_devices": max(device_counts)}
-    proc = subprocess.run([sys.executable, "-c", script], env=env,
-                          capture_output=True, text=True, timeout=1800,
-                          cwd=repo)
-    if proc.returncode != 0:
-        raise RuntimeError(f"weak-scaling subprocess failed:\n"
-                           f"{proc.stderr[-2000:]}")
-    line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT:")][0]
+    from benchmarks.mst_figures import run_mesh_sizes
+
     rows = []
-    for r in json.loads(line[len("RESULT:"):]):
+    for r in run_mesh_sizes(weak_scaling_point, device_counts,
+                            edges_per_dev=edges_per_dev):
         rows.append((
             f"sharded_weak_e{edges_per_dev}_d{r['n_dev']}", r["us"],
             f"edges_per_dev={r['edges_per_dev']};"

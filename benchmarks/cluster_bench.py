@@ -26,6 +26,7 @@ from typing import List, Sequence, Tuple
 import numpy as np
 
 from benchmarks.bench_io import JSON_PATH, merge_bench_json
+from repro.compile_cache import enable_compile_cache
 
 # (kind, n, dim, knn_k) cells.  The smoke cell is a subset of the default
 # set so the CI regression job always has a committed baseline key; uniform
@@ -110,6 +111,7 @@ def merge_json(rows: List[Tuple[str, float, str]], path: str) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="reduced shape set for the CI bench-regression job")

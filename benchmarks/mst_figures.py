@@ -1,16 +1,18 @@
 """Benchmark harness: one function per paper figure (Durbhakula 2020).
 
 Figure 1: optimized vs unoptimized sequential Borůvka (covered-edge filter).
-Figure 2: lock-variant across worker counts (edge shards on forced host
-          devices - the SPMD analogue of the paper's thread sweep).
+Figure 2: lock-variant across worker counts (edge shards over a device
+          mesh - the SPMD analogue of the paper's thread sweep: the
+          chips of a TPU host, or forced host devices on a CPU).
 Figure 3: CAS-variant across worker counts.
 Figure 4: CAS vs lock at 4 workers.
 
-This container is a single CPU core, so multi-device wall-clock speedup is
-interleaved, not parallel (the paper's 6C/12T machine is the target).  Each
-figure therefore reports BOTH wall time and the structural work metrics
-(rounds, lock waves) that determine the multicore behaviour; the dry-run
-artifacts carry the 256-chip collective roofline for the same algorithm.
+Forced host devices share the CPU's cores, so their multi-device wall-clock
+speedup is interleaved, not parallel (the paper's 6C/12T machine is the
+target).  Each figure therefore reports BOTH wall time and the structural
+work metrics (rounds, lock waves) that determine the multicore behaviour;
+the dry-run artifacts carry the 256-chip collective roofline for the same
+algorithm.
 """
 from __future__ import annotations
 
@@ -83,43 +85,73 @@ def fig1_sequential_optimization(graphs=DEFAULT_GRAPHS, repeats: int = 3):
     return rows
 
 
-_WORKER_SCRIPT = r"""
-import os, sys, time, json
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%d"
-import jax
-from repro.graphs.generator import paper_graph
-from repro.core.distributed_mst import distributed_msf, make_flat_mesh
-g = paper_graph("%s", seed=0)
-mesh = make_flat_mesh(%d)
-def run():
-    r = distributed_msf(g, mesh=mesh, variant="%s")
-    r.total_weight.block_until_ready()
-    return r
-r = run()
-t0 = time.perf_counter(); run(); dt = (time.perf_counter() - t0) * 1e6
-print("RESULT:" + json.dumps({
-    "us": dt, "rounds": int(r.num_rounds), "waves": int(r.num_waves)}))
-"""
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run_worker(graph: str, devices: int, variant: str):
+def run_mesh_sizes(point, device_counts, **kwargs):
+    """``[point(n, **kwargs) for n in device_counts]`` on the right devices.
+
+    On a TPU backend every size runs in this process over the first ``n``
+    chips, and sizes above the chip count are skipped: a child process
+    could not reach the chips this process holds.  Elsewhere one CPU-only
+    child forces ``max(device_counts)`` host devices (the flag must be set
+    before JAX initializes) and runs every size.  ``point`` must be a
+    module-level function and ``kwargs`` JSON-serializable.
+    """
+    import jax
+
+    if jax.default_backend() == "tpu":
+        n_chips = len(jax.devices())
+        return [point(n, **kwargs) for n in device_counts if n <= n_chips]
     env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    env.pop("JAX_PLATFORMS", None)
-    script = _WORKER_SCRIPT % (devices, graph, devices, variant)
-    proc = subprocess.run([sys.executable, "-c", script], env=env,
-                          capture_output=True, text=True, timeout=1200)
+    paths = [_REPO, os.path.join(_REPO, "src")]
+    if env.get("PYTHONPATH"):
+        paths.append(env["PYTHONPATH"])
+    env["PYTHONPATH"] = os.pathsep.join(paths)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " --xla_force_host_"
+                        f"platform_device_count={max(device_counts)}")
+    code = ("import json, sys\n"
+            f"from {point.__module__} import {point.__name__} as point\n"
+            "kw, sizes = json.loads(sys.argv[1]), json.loads(sys.argv[2])\n"
+            "print('RESULT:' + json.dumps([point(n, **kw) for n in sizes]))")
+    proc = subprocess.run(
+        [sys.executable, "-c", code, json.dumps(kwargs),
+         json.dumps(list(device_counts))],
+        env=env, capture_output=True, text=True, timeout=1800, cwd=_REPO)
     if proc.returncode != 0:
-        raise RuntimeError(proc.stderr[-1000:])
+        raise RuntimeError(f"forced-host-device child failed:\n"
+                           f"{proc.stderr[-2000:]}")
     line = [l for l in proc.stdout.splitlines()
             if l.startswith("RESULT:")][0]
     return json.loads(line[len("RESULT:"):])
 
 
+def scaling_point(devices: int, graph: str, variant: str) -> dict:
+    """One fig2/3/4 point: a warm ``distributed_msf`` solve of ``graph``
+    over a ``devices``-wide mesh, timed after one untimed compile."""
+    from repro.core.distributed_mst import distributed_msf, make_flat_mesh
+    from repro.graphs.generator import paper_graph
+
+    g = paper_graph(graph, seed=0)
+    mesh = make_flat_mesh(devices)
+
+    def run():
+        r = distributed_msf(g, mesh=mesh, variant=variant)
+        r.total_weight.block_until_ready()
+        return r
+
+    r = run()
+    t0 = time.perf_counter()
+    run()
+    return {"devices": devices,
+            "us": (time.perf_counter() - t0) * 1e6,
+            "rounds": int(r.num_rounds), "waves": int(r.num_waves)}
+
+
 def fig23_parallel_scaling(variant: str, graph: str = "Graph100K_6",
                            workers=(1, 2, 4, 8)):
     """Paper Figs 2/3: parallel variant vs worker count + seq baselines."""
-    import jax
     from repro.core.mst import mst_optimized, mst_unoptimized
     from repro.graphs.generator import paper_graph
 
@@ -130,9 +162,9 @@ def fig23_parallel_scaling(variant: str, graph: str = "Graph100K_6",
                   .total_weight.block_until_ready(), reps=2)
     rows = [(f"fig_{variant}_{graph}_seq_unopt", t_unopt, ""),
             (f"fig_{variant}_{graph}_seq_opt", t_opt, "")]
-    for w in workers:
-        out = _run_worker(graph, w, variant)
-        rows.append((f"fig_{variant}_{graph}_p{w}", out["us"],
+    for out in run_mesh_sizes(scaling_point, workers, graph=graph,
+                              variant=variant):
+        rows.append((f"fig_{variant}_{graph}_p{out['devices']}", out["us"],
                      f"rounds={out['rounds']},waves={out['waves']},"
                      f"speedup_vs_unopt={t_unopt / out['us']:.3f},"
                      f"speedup_vs_opt={t_opt / out['us']:.3f}"))
@@ -140,9 +172,13 @@ def fig23_parallel_scaling(variant: str, graph: str = "Graph100K_6",
 
 
 def fig4_cas_vs_lock(graph: str = "Graph100K_6", workers: int = 4):
-    """Paper Fig 4: CAS improvement over lock variant at 4 workers."""
-    cas = _run_worker(graph, workers, "cas")
-    lock = _run_worker(graph, workers, "lock")
+    """Paper Fig 4: CAS improvement over lock variant at 4 workers (no
+    rows on a TPU host with fewer chips)."""
+    pair = [run_mesh_sizes(scaling_point, (workers,), graph=graph,
+                           variant=v) for v in ("cas", "lock")]
+    if not all(pair):
+        return []
+    cas, lock = pair[0][0], pair[1][0]
     ratio = lock["us"] / cas["us"]
     return [(f"fig4_{graph}_cas_p{workers}", cas["us"],
              f"rounds={cas['rounds']}"),

@@ -15,6 +15,7 @@ import argparse
 import sys
 
 from benchmarks.bench_io import JSON_PATH, merge_bench_json, phase_split
+from repro.compile_cache import enable_compile_cache
 
 
 def solver_cache_rows(graph_name: str, repeats: int):
@@ -57,6 +58,7 @@ def solver_cache_rows(graph_name: str, repeats: int):
 
 
 def main() -> None:
+    enable_compile_cache()
     from repro.core import ENGINES
 
     engine_help = "; ".join(f"{name}: {spec.description}"

@@ -17,9 +17,11 @@ from repro.cluster import brute_force_labels
 from repro.core import ENGINES
 from repro.graphs.generator import POINT_CLOUDS, generate_points
 from repro.serve.mst_service import MSTService
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--points", type=int, default=400)
     ap.add_argument("--clusters", type=int, default=3)

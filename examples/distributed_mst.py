@@ -1,10 +1,14 @@
-"""Multi-worker distributed Borůvka demo (8 forced host devices).
+"""Multi-worker distributed Borůvka demo over every local device.
 
 Runs BOTH mesh engines through the registry: ``distributed`` (edge scan
 sharded, topology replicated, DESIGN.md §2) and ``sharded`` (topology
 shard-local with the owner-decode collective, DESIGN.md §2a), and prints
 each one's per-device topology footprint — the number the sharded engine
 exists to shrink.
+
+On a TPU host the mesh spans its chips; on a CPU-only machine it spans 8
+forced host devices (the flag only affects the CPU platform and must be
+set before JAX initializes).
 
     PYTHONPATH=src python examples/distributed_mst.py
 """
@@ -21,11 +25,13 @@ from repro.core.distributed_mst import make_flat_mesh  # noqa: E402
 from repro.core.oracle import kruskal_numpy  # noqa: E402
 from repro.graphs.generator import generate_graph  # noqa: E402
 from repro.graphs.partition_edges import partition_edges  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main():
-    n_dev = 8
-    print(f"devices: {len(jax.devices())}")
+    enable_compile_cache()
+    n_dev = len(jax.devices())
+    print(f"devices: {n_dev} x {jax.devices()[0].device_kind}")
     mesh = make_flat_mesh(n_dev)
     graph = generate_graph(50_000, 6, seed=0)
     oracle_mask, oracle_w, _ = kruskal_numpy(graph.src, graph.dst,

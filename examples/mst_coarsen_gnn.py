@@ -17,9 +17,11 @@ from repro.core.coarsen import boruvka_coarsen, coarsen_edges, \
 from repro.models.gnn import gnn_loss, init_gnn_params
 from repro.train import data as data_lib
 from repro.train.train_loop import run_training
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     cfg = ARCHS["gin-tu"].smoke
     n, e, d, classes = 600, 2400, 16, 5
     key = jax.random.key(0)

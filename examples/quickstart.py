@@ -15,9 +15,11 @@ import numpy as np
 from repro.core import ENGINES, SolveOptions, make_solver
 from repro.core.oracle import kruskal_numpy
 from repro.graphs.generator import generate_graph
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--nodes", type=int, default=20_000)
     ap.add_argument("--degree", type=float, default=6)

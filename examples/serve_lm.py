@@ -12,9 +12,11 @@ import jax.numpy as jnp
 
 from repro.configs.registry import ARCHS
 from repro.models.transformer import init_cache, init_lm_params, serve_step
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-27b",
                     choices=[a for a, e in ARCHS.items()

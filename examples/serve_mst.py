@@ -16,9 +16,11 @@ from repro.core import ENGINES
 from repro.core.oracle import kruskal_numpy
 from repro.graphs.generator import generate_graph
 from repro.serve.mst_service import MSTService
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--variant", default="cas", choices=["cas", "lock"])

@@ -18,6 +18,7 @@ from repro.configs.registry import ARCHS
 from repro.models.transformer import init_lm_params, lm_loss
 from repro.train import data as data_lib
 from repro.train.train_loop import run_training
+from repro.compile_cache import enable_compile_cache
 
 PRESETS = {
     "tiny": (ARCHS["tinyllama-1.1b"].smoke, 4, 32),
@@ -29,6 +30,7 @@ PRESETS = {
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", choices=sorted(PRESETS), default="tiny")
     ap.add_argument("--steps", type=int, default=30)

@@ -70,6 +70,9 @@ def build_doc() -> dict:
 
 
 def main(argv=None) -> int:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=DEFAULT_OUT,
                     help=f"output path (default: {DEFAULT_OUT})")

@@ -11,6 +11,7 @@ import jax
 from repro.core.mst import (minimum_spanning_forest, mst_optimized,
                             mst_unoptimized)
 from repro.graphs.generator import paper_graph
+from repro.compile_cache import enable_compile_cache
 
 
 def t(fn, reps=2):
@@ -22,6 +23,7 @@ def t(fn, reps=2):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--graph", default="Graph1M_9")
     args = ap.parse_args()

@@ -42,7 +42,6 @@ from repro.core.engine import (
     resolve_candidates,
     scan_bucket_index,
     scan_bucket_sizes,
-    shard_map_compat,
     validate_variant,
 )
 from repro.core.union_find import pointer_jump, count_components
@@ -139,10 +138,12 @@ def distributed_msf(graph: Graph, *, num_nodes: Optional[int] = None, mesh: Mesh
         return (final.parent, final.mst_mask, final.num_rounds,
                 final.num_waves, total, ncomp)
 
-    run_sharded = shard_map_compat(
+    run_sharded = jax.shard_map(
         run, mesh=mesh,
         in_specs=(shard, shard, shard, repl, repl, repl, repl),
-        out_specs=repl)
+        # Outputs are replicated by the pmin merges, which the checker
+        # cannot infer through the while_loop.
+        out_specs=repl, check_vma=False)
     with annotate("distributed_msf"):
         parent, mst_mask, rounds, waves, total, ncomp = run_sharded(
             scan_src, scan_dst, scan_rank, graph.src, graph.dst, order,
@@ -154,6 +155,12 @@ def distributed_msf(graph: Graph, *, num_nodes: Optional[int] = None, mesh: Mesh
 
 def make_flat_mesh(num_devices: Optional[int] = None,
                    axis: str = "data") -> Mesh:
-    """1-D mesh over the first ``num_devices`` local devices."""
-    devs = np.array(jax.devices()[:num_devices])
-    return Mesh(devs, (axis,))
+    """1-D mesh over the first ``num_devices`` local devices (all of them
+    when None); raises when fewer devices exist than were asked for."""
+    devs = jax.devices()
+    if num_devices is not None:
+        if num_devices > len(devs):
+            raise ValueError(f"asked for a {num_devices}-device mesh, but "
+                             f"only {len(devs)} devices exist")
+        devs = devs[:num_devices]
+    return Mesh(np.array(devs), (axis,))

@@ -61,25 +61,6 @@ def validate_variant(variant: str) -> str:
 
 
 # ---------------------------------------------------------------------------
-# shard_map compatibility (jax 0.4.x exposes it under jax.experimental).
-# ---------------------------------------------------------------------------
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, on any jax >= 0.4.30.
-
-    jax 0.4.x has neither ``jax.shard_map`` nor the ``check_vma`` kwarg; the
-    experimental entry point spells it ``check_rep``.
-    """
-    try:
-        sm = jax.shard_map
-        kwargs = {"check_vma": False}
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm
-        kwargs = {"check_rep": False}
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-# ---------------------------------------------------------------------------
 # Edge ranking: "distinct weights" as a structural property.
 # ---------------------------------------------------------------------------
 

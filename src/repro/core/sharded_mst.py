@@ -51,7 +51,6 @@ from repro.core.engine import (
     partner_components,
     scan_bucket_index,
     scan_bucket_sizes,
-    shard_map_compat,
     validate_variant,
 )
 from repro.core.union_find import pointer_jump, count_components
@@ -252,12 +251,12 @@ def sharded_msf(graph: Graph, *, num_nodes: Optional[int] = None, mesh: Mesh,
         return (final.parent, final.mst_mask, final.num_rounds,
                 final.num_waves, ncomp)
 
-    run_sharded = shard_map_compat(
+    run_sharded = jax.shard_map(
         run, mesh=mesh,
         in_specs=(shard, shard, shard, shard),
         # mst_mask stays sharded through the whole solve; out_specs P(axis)
         # is the single gather that assembles the global mask.
-        out_specs=(repl, shard, repl, repl, repl))
+        out_specs=(repl, shard, repl, repl, repl), check_vma=False)
     with annotate("sharded_msf"):
         parent, mask_pad, rounds, waves, ncomp = run_sharded(
             s_src, s_dst, s_rank, s_gid)

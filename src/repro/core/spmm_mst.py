@@ -22,9 +22,10 @@ ELL(+overflow) adjacency (``graphs/csr_device.py``): a fixed-shape
 ``(V, D)`` gather/filter/row-min plus a V-sized segment combine —
 vertex-dimension cost, contiguous accesses, no big scatter.  Measured on
 Graph100K_6 mid-solve the ELL selection is ~4x faster than the edge-list
-scan.  ``kernels/gnn_spmm.gather_segment_min`` is the Pallas TPU kernel
-of the same semiring reduction; the jnp formulation here is the portable
-(and on CPU, faster) path, and both are pinned equal in the kernel sweep.
+scan.  ``kernels/gnn_spmm.gather_segment_min`` is the Pallas kernel
+of the same semiring reduction; the jnp formulation here is the default
+path on every backend (the TPU compiler refuses the kernel, see
+:func:`resolve_kernel`), and both are pinned equal in the kernel sweep.
 
 Everything *after* candidate selection — decode, cas/lock hooking,
 commit, round accounting — is the shared ``engine.hook_commit_round``,
@@ -106,7 +107,8 @@ def spmm_candidates(ell: EllGraph, parent) -> jnp.ndarray:
 
 def spmm_candidates_kernel(ell: EllGraph, parent) -> jnp.ndarray:
     """``spmm_candidates`` through the Pallas ``gather_segment_min``
-    kernel — the TPU path of the same (min, cut-filter) semiring.
+    kernel — the same (min, cut-filter) semiring; opt-in only
+    (:func:`resolve_kernel`).
 
     The ELL block plus overflow tail flatten to one slot stream
     (row, col, key); the kernel's cut filter and scatter-min see exactly
@@ -138,12 +140,18 @@ def spmm_candidates_kernel(ell: EllGraph, parent) -> jnp.ndarray:
     return gather_segment_min(row, col, key, parent, num_nodes=v)
 
 
-def _resolve_kernel(kernel: Optional[bool]) -> bool:
-    """Backend gate: the Pallas path is the default on TPU only (on CPU
-    the kernel runs in interpret mode — correct, pinned by conformance,
-    but far slower than the jnp reduction)."""
-    return jax.default_backend() == "tpu" if kernel is None else \
-        bool(kernel)
+def resolve_kernel(kernel: Optional[bool] = None) -> bool:
+    """Candidate-path gate: the jnp reduction is the default on every
+    backend, the Pallas ``gather_segment_min`` kernel runs only when asked
+    for with ``kernel=True``.
+
+    The kernel reads labels at data-dependent 1-element offsets of VMEM,
+    which the TPU compiler refuses (it must prove every vector load
+    aligned to the tiling), and keeps the whole (V+1,) label vector and
+    accumulator resident in VMEM.  Off-TPU it runs in interpret mode,
+    where the conformance cell pins it equal to the jnp path.
+    """
+    return bool(kernel)
 
 
 @functools.partial(jax.jit,
@@ -385,16 +393,16 @@ def spmm_msf(graph: Graph, *, num_nodes: Optional[int] = None,
         Requires ``compaction > 0``.
       kernel: route candidate selection through the Pallas
         ``gather_segment_min`` kernel instead of the jnp reduction.
-        None (default) is the backend gate: kernel on TPU, jnp
-        elsewhere.  True forces the kernel (interpret mode off-TPU —
-        the conformance cell's path); both paths are bit-identical.
+        None/False (default) take the jnp path on every backend (see
+        :func:`resolve_kernel`); True runs the kernel (interpret mode
+        off-TPU — the conformance cell's path); both are bit-identical.
     """
     graph = ensure_sized(graph, num_nodes)
     validate_variant(variant)
     if contraction and not compaction:
         raise ValueError("contraction requires compaction > 0 "
                          "(layout rebuilds happen at epoch boundaries)")
-    use_kernel = _resolve_kernel(kernel)
+    use_kernel = resolve_kernel(kernel)
     rank, order = rank_edges_host(graph.weight)
     if compaction:
         return _spmm_host_loop(graph, rank, order, variant=variant,

@@ -5,9 +5,11 @@ Each kernel package has:
   ops.py    - jit'd public wrapper (block-size selection, interpret switch)
   ref.py    - pure-jnp oracle used by the allclose test sweeps
 
-This container is CPU-only: kernels are validated with interpret=True, which
-executes the kernel body in Python; the BlockSpecs encode the real VMEM
-tiling the TPU target would use.
+On a TPU backend the kernels compile for the chip; elsewhere (the CPU test
+suite) ``interpret`` defaults to True, which executes the kernel body in
+Python while the BlockSpecs still encode the VMEM tiling the chip uses.
+``tests/test_tpu_compile.py`` compiles the kernels on default chip paths
+for a described TPU v5e.
 """
 from repro.kernels.segment_min_edges.ops import (batched_segment_min_edges,
                                                  segment_min_edges)

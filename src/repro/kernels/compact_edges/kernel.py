@@ -39,17 +39,16 @@ def _kernel(cov_ref, perm_ref, cnt_ref):
     @pl.when(phase == 0)
     def _count():
         # Live total accumulates in cnt[0] across the phase-0 sweep.
-        cur = pl.load(cnt_ref, (pl.dslice(0, 1),))
         alive = jnp.sum(1 - cov_ref[...]).astype(jnp.int32)
-        pl.store(cnt_ref, (pl.dslice(0, 1),), cur + alive)
+        cnt_ref[pl.ds(0, 1)] = cnt_ref[pl.ds(0, 1)] + alive
 
     @pl.when((phase == 1) & (blk == 0))
     def _cursors():
         # cnt[0] -> live cursor (restarts at 0), cnt[1] -> dead cursor
         # (starts at the live total counted in phase 0).
-        live_total = pl.load(cnt_ref, (pl.dslice(0, 1),))
-        pl.store(cnt_ref, (pl.dslice(1, 1),), live_total)
-        pl.store(cnt_ref, (pl.dslice(0, 1),), jnp.zeros_like(live_total))
+        live_total = cnt_ref[pl.ds(0, 1)]
+        cnt_ref[pl.ds(1, 1)] = live_total
+        cnt_ref[pl.ds(0, 1)] = jnp.zeros_like(live_total)
 
     @pl.when(phase == 1)
     def _assign():
@@ -57,10 +56,9 @@ def _kernel(cov_ref, perm_ref, cnt_ref):
 
         def body(i, _):
             dead = cov_ref[i]  # 0 = live -> cursor cnt[0], 1 -> cnt[1]
-            slot = pl.load(cnt_ref, (pl.dslice(dead, 1),))
-            pl.store(perm_ref, (pl.dslice(slot[0], 1),),
-                     jnp.full((1,), base + i, jnp.int32))
-            pl.store(cnt_ref, (pl.dslice(dead, 1),), slot + 1)
+            slot = cnt_ref[pl.ds(dead, 1)]
+            perm_ref[pl.ds(slot[0], 1)] = jnp.full((1,), base + i, jnp.int32)
+            cnt_ref[pl.ds(dead, 1)] = slot + 1
             return 0
 
         jax.lax.fori_loop(0, block, body, 0)

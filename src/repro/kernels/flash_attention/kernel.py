@@ -34,10 +34,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, window, cap,
 
     def body(j, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.dslice(j * block_kv, block_kv),
-                            slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(j * block_kv, block_kv),
-                            slice(None))).astype(jnp.float32)
+        k = k_ref[pl.ds(j * block_kv, block_kv), :].astype(jnp.float32)
+        v = v_ref[pl.ds(j * block_kv, block_kv), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if cap is not None:
@@ -77,9 +75,7 @@ def flash_attention_pallas(q, k, v, *, scale: float, causal: bool = True,
     g = h // hkv
     assert sq % block_q == 0 and skv % block_kv == 0
     grid = (b, h, sq // block_q)
-    # None-squeezed batch/head dims: kernel refs are 2-D (seq, hd) blocks, a
-    # single indexer per load/store (jax 0.4.37's interpret-mode discharge
-    # rule rejects the stacked `.at[0, 0]` + dslice form).
+    # None-squeezed batch/head dims: kernel refs are 2-D (seq, hd) blocks.
     q_spec = pl.BlockSpec((None, None, block_q, hd),
                           lambda bi, hi, qi: (bi, hi, qi, 0))
     # GQA: the kv-head index comes from the INDEX MAP - no repeat in memory.

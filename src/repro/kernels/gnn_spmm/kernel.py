@@ -52,10 +52,8 @@ def _sum_kernel(src_ref, dst_ref, w_ref, feat_ref, out_ref):
         s = src_ref[i]
         d = dst_ref[i]
         w = w_ref[i]
-        row = pl.load(feat_ref, (pl.dslice(s, 1), slice(None)))
-        cur = pl.load(out_ref, (pl.dslice(d, 1), slice(None)))
-        pl.store(out_ref, (pl.dslice(d, 1), slice(None)),
-                 cur + row * w)
+        row = feat_ref[pl.ds(s, 1), :]
+        out_ref[pl.ds(d, 1), :] = out_ref[pl.ds(d, 1), :] + row * w
         return 0
 
     jax.lax.fori_loop(0, block, body, 0)
@@ -99,13 +97,13 @@ def _min_kernel(row_ref, col_ref, key_ref, label_ref, out_ref):
         r = row_ref[i]
         c = col_ref[i]
         k = key_ref[i]
-        lr = pl.load(label_ref, (pl.dslice(r, 1),))
-        lc = pl.load(label_ref, (pl.dslice(c, 1),))
+        lr = label_ref[pl.ds(r, 1)]
+        lc = label_ref[pl.ds(c, 1)]
         # Semiring "multiply": the cut filter.  An intra-component slot is
         # a semiring zero (sentinel key never wins the min).
         key = jnp.where(lr != lc, k, INT_SENTINEL)
-        cur = pl.load(out_ref, (pl.dslice(lr[0], 1),))
-        pl.store(out_ref, (pl.dslice(lr[0], 1),), jnp.minimum(cur, key))
+        out_ref[pl.ds(lr[0], 1)] = jnp.minimum(out_ref[pl.ds(lr[0], 1)],
+                                               key)
         return 0
 
     jax.lax.fori_loop(0, block, body, 0)

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+INT_MAX = jnp.iinfo(jnp.int32).max
 
 def _kernel(xr_ref, xc_ref, idx_ref, dist_ref, *, k: int, n_real: int,
             block_rows: int, block_cols: int):
@@ -63,8 +64,10 @@ def _kernel(xr_ref, xc_ref, idx_ref, dist_ref, *, k: int, n_real: int,
     for _ in range(k):
         m = jnp.argmin(cand_d, axis=1)  # first occurrence on ties
         new_d.append(jnp.min(cand_d, axis=1))
-        new_i.append(jnp.take_along_axis(cand_i, m[:, None], axis=1)[:, 0])
-        cand_d = jnp.where(lane == m[:, None], jnp.inf, cand_d)
+        hit = lane == m[:, None]
+        # Exactly one lane per row hits, so a masked min selects its id.
+        new_i.append(jnp.min(jnp.where(hit, cand_i, INT_MAX), axis=1))
+        cand_d = jnp.where(hit, jnp.inf, cand_d)
     dist_ref[...] = jnp.stack(new_d, axis=1)
     idx_ref[...] = jnp.stack(new_i, axis=1)
 

@@ -32,7 +32,12 @@ def knn_graph(points, *, k: int, block_rows: int = 128,
     if not 1 <= k <= n - 1:
         raise ValueError(f"need 1 <= k <= n-1, got k={k} for n={n}")
     points = points.astype(jnp.float32)
-    br = min(block_rows, max(8, n))
+    # The unrolled top-k merge keeps ~k live (block_rows, block_cols + k)
+    # temporaries in VMEM; capping block_rows * k at 8192 keeps them in
+    # the TPU's 16 MiB scoped VMEM at every escalated k (128 rows at the
+    # default k, 64 rows at k = 128).
+    rows_for_k = 1 << (max(1, 8192 // k).bit_length() - 1)  # pow2 floor
+    br = min(block_rows, max(8, n), max(8, rows_for_k))
     bc = min(block_cols, max(8, n))
     step = math.lcm(br, bc)
     pad = (-n) % step

@@ -49,15 +49,13 @@ def _kernel(isroot_ref, newid_ref, cnt_ref):
     @pl.when(phase == 0)
     def _count():
         # Root total accumulates in cnt[0] across the phase-0 sweep.
-        cur = pl.load(cnt_ref, (pl.dslice(0, 1),))
         roots = jnp.sum(isroot_ref[...]).astype(jnp.int32)
-        pl.store(cnt_ref, (pl.dslice(0, 1),), cur + roots)
+        cnt_ref[pl.ds(0, 1)] = cnt_ref[pl.ds(0, 1)] + roots
 
     @pl.when((phase == 1) & (blk == 0))
     def _cursor():
         # cnt[0] -> root total (phase-0 result), cnt[1] -> assign cursor.
-        pl.store(cnt_ref, (pl.dslice(1, 1),),
-                 jnp.zeros((1,), jnp.int32))
+        cnt_ref[pl.ds(1, 1)] = jnp.zeros((1,), jnp.int32)
 
     @pl.when(phase == 1)
     def _assign():
@@ -65,11 +63,10 @@ def _kernel(isroot_ref, newid_ref, cnt_ref):
 
         def body(i, _):
             root = isroot_ref[i]
-            cur = pl.load(cnt_ref, (pl.dslice(1, 1),))
+            cur = cnt_ref[pl.ds(1, 1)]
             val = jnp.where(root == 1, cur[0], INT_SENTINEL)
-            pl.store(newid_ref, (pl.dslice(base + i, 1),),
-                     jnp.full((1,), val, jnp.int32))
-            pl.store(cnt_ref, (pl.dslice(1, 1),), cur + root)
+            newid_ref[pl.ds(base + i, 1)] = jnp.full((1,), val, jnp.int32)
+            cnt_ref[pl.ds(1, 1)] = cur + root
             return 0
 
         jax.lax.fori_loop(0, block, body, 0)

@@ -47,10 +47,8 @@ def _kernel(keys_ref, cu_ref, cv_ref, out_ref):
         v = cv_ref[i]
         # scatter-min into the VMEM-resident minimum[] (both endpoints:
         # undirected edge offers itself to both components).
-        cur_u = pl.load(out_ref, (pl.dslice(u, 1),))
-        pl.store(out_ref, (pl.dslice(u, 1),), jnp.minimum(cur_u, k))
-        cur_v = pl.load(out_ref, (pl.dslice(v, 1),))
-        pl.store(out_ref, (pl.dslice(v, 1),), jnp.minimum(cur_v, k))
+        out_ref[pl.ds(u, 1)] = jnp.minimum(out_ref[pl.ds(u, 1)], k)
+        out_ref[pl.ds(v, 1)] = jnp.minimum(out_ref[pl.ds(v, 1)], k)
         return 0
 
     jax.lax.fori_loop(0, block, body, 0)
@@ -88,16 +86,16 @@ def _batched_kernel(keys_ref, cu_ref, cv_ref, out_ref):
 
     block = keys_ref.shape[1]
 
-    lane = pl.dslice(0, 1)  # block shape is (1, ...): single-lane row
+    lane = pl.ds(0, 1)  # block shape is (1, ...): single-lane row
 
     def body(i, _):
-        k = pl.load(keys_ref, (lane, pl.dslice(i, 1)))
-        u = pl.load(cu_ref, (lane, pl.dslice(i, 1)))[0, 0]
-        v = pl.load(cv_ref, (lane, pl.dslice(i, 1)))[0, 0]
-        cur_u = pl.load(out_ref, (lane, pl.dslice(u, 1)))
-        pl.store(out_ref, (lane, pl.dslice(u, 1)), jnp.minimum(cur_u, k))
-        cur_v = pl.load(out_ref, (lane, pl.dslice(v, 1)))
-        pl.store(out_ref, (lane, pl.dslice(v, 1)), jnp.minimum(cur_v, k))
+        k = keys_ref[lane, pl.ds(i, 1)]
+        u = cu_ref[lane, pl.ds(i, 1)][0, 0]
+        v = cv_ref[lane, pl.ds(i, 1)][0, 0]
+        out_ref[lane, pl.ds(u, 1)] = jnp.minimum(out_ref[lane, pl.ds(u, 1)],
+                                                 k)
+        out_ref[lane, pl.ds(v, 1)] = jnp.minimum(out_ref[lane, pl.ds(v, 1)],
+                                                 k)
         return 0
 
     jax.lax.fori_loop(0, block, body, 0)

@@ -1,7 +1,8 @@
 """Pallas kernel sweeps: shapes x dtypes vs the pure-jnp ref oracles.
 
-All kernels run interpret=True (CPU container); BlockSpecs encode the TPU
-tiling.  Tolerances: fp32 1e-5; bf16 inputs 2e-2 (per the public
+The tests run on the CPU, so every kernel runs in interpret mode; the
+BlockSpecs still encode the TPU tiling, and ``test_tpu_compile.py``
+compiles the chip-path kernels for a described v5e.  Tolerances: fp32 1e-5; bf16 inputs 2e-2 (per the public
 FlashAttention/Triton test precedent).
 """
 import jax

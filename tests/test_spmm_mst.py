@@ -13,8 +13,8 @@ import pytest
 
 from repro.core.engine import candidate_min_edges, rank_edges_host
 from repro.core.mst import minimum_spanning_forest
-from repro.core.spmm_mst import (spmm_candidates, spmm_candidates_kernel,
-                                 spmm_msf)
+from repro.core.spmm_mst import (resolve_kernel, spmm_candidates,
+                                 spmm_candidates_kernel, spmm_msf)
 from repro.core.types import Graph, INT_SENTINEL
 from repro.graphs.csr_device import ell_from_edges_host
 from repro.graphs.generator import generate_graph
@@ -103,6 +103,17 @@ def test_spmm_kernel_full_solve_bit_identical(variant):
         assert (np.asarray(got.mst_mask) == np.asarray(ref.mst_mask)).all()
         assert int(got.num_rounds) == int(ref.num_rounds)
         assert int(got.num_waves) == int(ref.num_waves)
+
+
+def test_spmm_gate_takes_jnp_path_on_tpu(monkeypatch):
+    """On a TPU backend the default candidate path is the jnp reduction:
+    the chip's compiler refuses the Pallas kernel, so only an explicit
+    ``kernel=True`` reaches it."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_kernel() is False
+    assert resolve_kernel(None) is False
+    assert resolve_kernel(True) is True
 
 
 @pytest.mark.parametrize("variant", VARIANTS)
