@@ -214,18 +214,20 @@ def batched_msf(batch: BatchedGraph, *, num_nodes: int,
         final, _ = jax.lax.while_loop(
             cond, body, (init, init_frontier(batch.src, batch.dst, rank)))
 
-    final = jax.vmap(materialize_commits)(final)
-    total = jnp.sum(jnp.where(final.mst_mask, batch.weight, 0.0), axis=1)
-    comp = jax.vmap(count_components)(final.parent)
-    pad_singletons = jnp.int32(num_nodes) - batch.num_nodes
-    return BatchedMSTResult(
-        parent=final.parent,
-        mst_mask=final.mst_mask,
-        num_rounds=final.num_rounds,
-        num_waves=final.num_waves,
-        total_weight=total,
-        num_components=comp - pad_singletons,
-    )
+    with jax.named_scope("mst.finish"):
+        final = jax.vmap(materialize_commits)(final)
+        total = jnp.sum(jnp.where(final.mst_mask, batch.weight, 0.0),
+                        axis=1)
+        comp = jax.vmap(count_components)(final.parent)
+        pad_singletons = jnp.int32(num_nodes) - batch.num_nodes
+        return BatchedMSTResult(
+            parent=final.parent,
+            mst_mask=final.mst_mask,
+            num_rounds=final.num_rounds,
+            num_waves=final.num_waves,
+            total_weight=total,
+            num_components=comp - pad_singletons,
+        )
 
 
 def _contracted_loop(batch: BatchedGraph, rank, order, init, *,
@@ -269,6 +271,7 @@ def _contracted_loop(batch: BatchedGraph, rank, order, init, *,
         num_active=batch.num_nodes.astype(jnp.int32)))
 
 
+@jax.named_scope("mst.finish")
 def _finish_contracted(batch: BatchedGraph, fin: ContractCarry, *,
                        num_nodes: int) -> BatchedMSTResult:
     """Per-lane original-id reconstruction from the root-translation table.

@@ -31,6 +31,16 @@ engine periodically stable-partitions the live lanes to a prefix
 (``boruvka_epoch`` / ``scan_bucket_sizes``).  The pow2 bucketing is the
 same recompile-bounding idea as ``graphs/batching.py``, applied inside a
 single jitted ``while_loop`` via ``lax.switch`` over statically-sized slices.
+
+Every block is traced under a device phase name (``jax.named_scope``,
+metadata only), so a profiler trace maps each XLA op to a step of the
+round whatever the engine (DESIGN.md §4): ``mst.scan`` (endpoint-label
+gathers, covered mask, candidate minima: the E-sized work), ``mst.hook``
+(decode, hooking, commit: the V-sized work), ``mst.jump`` (pointer
+jumping), ``mst.sort`` (in-jit edge ranking), ``mst.compact`` (the
+compaction and contraction epochs around the rounds) and ``mst.finish``
+(commit flush, result).  The innermost scope wins, so a round inside an
+epoch keeps its own names.
 """
 from __future__ import annotations
 
@@ -64,6 +74,7 @@ def validate_variant(variant: str) -> str:
 # Edge ranking: "distinct weights" as a structural property.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("mst.sort")
 def rank_edges(weight: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Dense rank of every edge under (weight, edge_id) lexicographic order.
 
@@ -129,6 +140,7 @@ def init_state(num_nodes: int, e_full: int, e_scan: int,
     )
 
 
+@jax.named_scope("mst.finish")
 def materialize_commits(state: BoruvkaState) -> BoruvkaState:
     """Flush the (V,) CAS commit slots into the (E,) mask — one scatter
     per solve.  No-op for states without commit slots."""
@@ -138,6 +150,7 @@ def materialize_commits(state: BoruvkaState) -> BoruvkaState:
     return state._replace(mst_mask=mask)
 
 
+@jax.named_scope("mst.finish")
 def finish_result(graph: Graph, state: BoruvkaState, rounds) -> MSTResult:
     total = jnp.sum(jnp.where(state.mst_mask, graph.weight, 0.0))
     return MSTResult(
@@ -182,6 +195,7 @@ def init_frontier(scan_src, scan_dst, scan_rank, edge_id=None) -> Frontier:
     return Frontier(scan_src, scan_dst, scan_rank, live, edge_id)
 
 
+@jax.named_scope("mst.compact")
 def live_prefix_permutation(covered) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Stable partition of lane ids on the covered bit.
 
@@ -202,6 +216,7 @@ def live_prefix_permutation(covered) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return perm, live
 
 
+@jax.named_scope("mst.compact")
 def compact_frontier(frontier: Frontier, covered,
                      *, use_kernel: bool = False
                      ) -> Tuple[Frontier, jnp.ndarray]:
@@ -349,6 +364,7 @@ def relabel_roots(isroot) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return new_id, jnp.sum(isroot, axis=-1).astype(jnp.int32)
 
 
+@jax.named_scope("mst.compact")
 def count_active_roots(parent, num_active) -> jnp.ndarray:
     """Roots among the active id range ``[0, num_active)`` — the live
     supervertex count the vertex buckets track (buffer ids beyond
@@ -436,6 +452,7 @@ def vertex_bucket_sizes(num_nodes: int,
     return scan_bucket_sizes(num_nodes, min_bucket)
 
 
+@jax.named_scope("mst.compact")
 def boruvka_contract_epoch(carry: ContractCarry, full_src, full_dst, order,
                            *, round_factory,
                            e_sizes: Tuple[int, ...],
@@ -521,6 +538,7 @@ def boruvka_contract_epoch(carry: ContractCarry, full_src, full_dst, order,
     return jax.lax.switch(idx, branches, carry)
 
 
+@jax.named_scope("mst.compact")
 def dedup_parallel_edges(cov, nsrc, ndst, rank, n_new):
     """Cover every non-minimal parallel edge between contracted endpoint
     pairs — the other half of true graph contraction, and the measured fix
@@ -559,6 +577,7 @@ def dedup_parallel_edges(cov, nsrc, ndst, rank, n_new):
 @functools.partial(
     jax.jit, static_argnames=("variant", "max_lock_waves", "compaction",
                               "use_kernel"))
+@jax.named_scope("mst.compact")
 def contract_epoch_host(parent, covered, committed, mst_mask, num_rounds,
                         num_waves, src, dst, rank, full_src, full_dst,
                         order, root_map, num_active, *, variant: str,
@@ -655,6 +674,7 @@ def contract_epoch_host(parent, covered, committed, mst_mask, num_rounds,
             new_id[st.parent[root_map]], n_new)
 
 
+@jax.named_scope("mst.compact")
 def respread_ranks(lane_rank, order):
     """Renumber surviving edge ranks to a dense ``[0, live)`` prefix at an
     epoch boundary (the ROADMAP PR-7 follow-up).
@@ -687,6 +707,7 @@ def respread_ranks(lane_rank, order):
 
 
 @functools.partial(jax.jit, static_argnames=("new_e", "new_v", "e_full"))
+@jax.named_scope("mst.compact")
 def contract_slice_host(nsrc, ndst, rank, order, perm, live, *, new_e: int,
                         new_v: int, e_full: int):
     """Materialize the next epoch's bucket-sized buffers from
@@ -705,6 +726,7 @@ def contract_slice_host(nsrc, ndst, rank, order, perm, live, *, new_e: int,
             jnp.full((new_v,), e_full, jnp.int32))    # CAS commit slots
 
 
+@jax.named_scope("mst.finish")
 def contracted_parent_original_ids(root_map, num_nodes: int) -> jnp.ndarray:
     """Translate the contracted component ids back to an original-id
     parent array: every vertex points at the minimum original vertex of
@@ -727,6 +749,7 @@ def make_scan_branches(sizes: Tuple[int, ...], num_nodes: int):
     whole-round-in-branch structure).
     """
     def scan_branch(sz):
+        @jax.named_scope("mst.scan")
         def scan(ops):
             parent, covered, f = ops
             cu_e = parent[f.src[:sz]]
@@ -741,6 +764,7 @@ def make_scan_branches(sizes: Tuple[int, ...], num_nodes: int):
     return [scan_branch(sz) for sz in sizes]
 
 
+@jax.named_scope("mst.compact")
 def maybe_pack_frontier(state: BoruvkaState, frontier: Frontier,
                         sizes: Tuple[int, ...], compaction: int
                         ) -> Tuple[BoruvkaState, Frontier]:
@@ -794,6 +818,7 @@ def scan_bucket_index(sizes: Tuple[int, ...], live) -> jnp.ndarray:
 # Per-round building blocks.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("mst.scan")
 def candidate_min_edges(key, cu, cv, num_nodes):
     """Per-component minimum outgoing edge rank (paper lines 15-28).
 
@@ -806,6 +831,7 @@ def candidate_min_edges(key, cu, cv, num_nodes):
     return jnp.minimum(best_u, best_v)  # (V,) rank or INT_SENTINEL
 
 
+@jax.named_scope("mst.hook")
 def resolve_candidates(best, order, full_src, full_dst, parent,
                        root_map=None):
     """Decode per-component candidate rank -> (edge id, endpoints, partner).
@@ -835,6 +861,7 @@ def resolve_candidates(best, order, full_src, full_dst, parent,
     return has, cand_edge, end_u, end_v, other, iota
 
 
+@jax.named_scope("mst.hook")
 def partner_components(parent, has, end_u, end_v):
     """Partner root of each component's candidate edge.
 
@@ -848,6 +875,7 @@ def partner_components(parent, has, end_u, end_v):
     return other, iota
 
 
+@jax.named_scope("mst.hook")
 def commit_edges(mst_mask, cand_edge, commit):
     """Scatter-commit candidate edges; non-committers scatter out of bounds
     (dropped), mirroring 'Add edge minimum[v] to the set M' under guard."""
@@ -860,6 +888,7 @@ def commit_edges(mst_mask, cand_edge, commit):
 # Hooking variants - the paper's two synchronization schemes, data-parallel.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("mst.hook")
 def hook_cas(parent, has, cand_edge, other, iota):
     """CAS-variant hooking (paper §2.2.2).
 
@@ -882,6 +911,7 @@ def hook_cas(parent, has, cand_edge, other, iota):
     return new_parent, commit
 
 
+@jax.named_scope("mst.hook")
 def hook_lock_waves(parent, mst_mask, has, cand_edge, end_u, end_v,
                     *, max_waves: int, commit_fn=commit_edges):
     """Lock-variant hooking (paper §2.2.1), as propose-verify *retry waves*.
@@ -960,6 +990,7 @@ def hook_lock_waves(parent, mst_mask, has, cand_edge, end_u, end_v,
 # One Borůvka round (replicated-topology layout).
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("mst.hook")
 def hook_commit_round(state: BoruvkaState, best, order, full_src, full_dst,
                       root_map=None, *, variant: str,
                       max_lock_waves: int = 16) -> BoruvkaState:
@@ -1014,12 +1045,13 @@ def boruvka_round(state: BoruvkaState, scan_src, scan_dst, scan_rank,
     decoded from the replicated topology into the contracted vertex space;
     the scan lanes themselves are already contracted-id.
     """
-    cu_e = state.parent[scan_src]
-    cv_e = state.parent[scan_dst]
-    self_edge = cu_e == cv_e
-    new_covered = state.covered | self_edge  # "graph_edge[E].covered = 1"
-    key = jnp.where(new_covered, INT_SENTINEL, scan_rank)
-    best = candidate_min_edges(key, cu_e, cv_e, num_nodes)
+    with jax.named_scope("mst.scan"):
+        cu_e = state.parent[scan_src]
+        cv_e = state.parent[scan_dst]
+        self_edge = cu_e == cv_e
+        new_covered = state.covered | self_edge  # "graph_edge[E].covered = 1"
+        key = jnp.where(new_covered, INT_SENTINEL, scan_rank)
+        best = candidate_min_edges(key, cu_e, cv_e, num_nodes)
     out = hook_commit_round(state, best, order, full_src, full_dst,
                             root_map, variant=variant,
                             max_lock_waves=max_lock_waves)
@@ -1027,6 +1059,7 @@ def boruvka_round(state: BoruvkaState, scan_src, scan_dst, scan_rank,
         covered=new_covered if track_covered else state.covered)
 
 
+@jax.named_scope("mst.compact")
 def boruvka_epoch(state: BoruvkaState, frontier: Frontier,
                   full_src, full_dst, order, *, round_fn,
                   sizes: Tuple[int, ...], compaction: int,

@@ -156,6 +156,7 @@ def sharded_msf(graph: Graph, *, num_nodes: Optional[int] = None, mesh: Mesh,
         sizes = scan_bucket_sizes(e_shard) if compaction else (e_shard,)
 
         def decode_branch(sz):
+            @jax.named_scope("mst.scan")
             def decode(ops):
                 # Owner-decode over the same prefix: the cheap gathers are
                 # recomputed (branch outputs must be shape-identical, so a

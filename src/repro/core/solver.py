@@ -39,7 +39,7 @@ from repro.core.types import Graph, GraphLike, MSTResult, as_request, \
     ensure_sized
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.span import current_span
-from repro.obs.trace import SolveTrace, annotate, collect_phases
+from repro.obs.trace import SolveTrace, annotate, collect_phases, pack_time
 
 
 @dataclasses.dataclass
@@ -185,8 +185,9 @@ class MSTSolver:
         latency is honest end-to-end wall time; every caller of a solve
         either blocks immediately after anyway (benchmarks, serving) or
         reads results right away.  Host-side phases deep in the engines
-        (``rank_edges_host`` -> "rank", packing helpers -> "pack") report
-        into a thread-local collector; ``solve_us`` is the remainder.
+        (``rank_edges_host`` -> "rank", lane packing -> "pack", result
+        trimming -> "trim") report into a thread-local collector;
+        ``solve_us`` is the remainder.
         ``reader(result)`` pulls ``(rounds, waves, mst_edges)`` — scalar
         device reads, performed after the block.
         """
@@ -198,7 +199,7 @@ class MSTSolver:
             total_us = (time.perf_counter() - t0) * 1e6
         host_phases = {k: v * 1e6 for k, v in phases.items()}
         rank_us = host_phases.get("rank", 0.0)
-        pack_us = host_phases.get("pack", 0.0)
+        pack_us = pack_time(host_phases)
         rounds, waves, mst_edges = reader(result)
         trace = SolveTrace(
             engine=self.options.engine, variant=self.options.variant,
@@ -271,9 +272,10 @@ class MSTSolver:
 
         from repro.graphs.batching import pack_graphs, unpack_results_mst
 
-        # The outer collector catches the "pack" phases (lane packing +
-        # result trimming) that run outside the per-bucket dispatches;
-        # the per-bucket traces get an even share of that wall time.
+        # The outer collector catches the "pack" and "trim" phases (lane
+        # packing, result trimming) that run outside the per-bucket
+        # dispatches; the per-bucket traces get an even share of that
+        # wall time.
         with collect_phases() as outer:
             buckets = pack_graphs(graphs, max_batch=self.options.max_batch)
             results, emitted = [], []
@@ -281,7 +283,7 @@ class MSTSolver:
                 results.append(self.solve_packed(b))
                 emitted.append(self.last_trace)
             out = unpack_results_mst(buckets, results)
-        pack_us = outer.get("pack", 0.0) * 1e6
+        pack_us = pack_time(outer) * 1e6
         if pack_us and emitted:
             self._h_pack.observe(pack_us)
             share = pack_us / len(emitted)

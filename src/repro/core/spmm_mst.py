@@ -74,6 +74,7 @@ from repro.graphs.csr_device import EllGraph, ell_from_edges, \
 from repro.kernels.gnn_spmm.ops import gather_segment_min
 
 
+@jax.named_scope("mst.scan")
 def spmm_candidates(ell: EllGraph, parent) -> jnp.ndarray:
     """One candidate-semiring SpMV: (V,) per-component min outgoing rank.
 
@@ -105,6 +106,7 @@ def spmm_candidates(ell: EllGraph, parent) -> jnp.ndarray:
     return best
 
 
+@jax.named_scope("mst.scan")
 def spmm_candidates_kernel(ell: EllGraph, parent) -> jnp.ndarray:
     """``spmm_candidates`` through the Pallas ``gather_segment_min``
     kernel — the same (min, cut-filter) semiring; opt-in only

@@ -70,8 +70,13 @@ class HostUnionFind:
             p = pp
 
 
+@jax.named_scope("mst.jump")
 def pointer_jump(parent: jnp.ndarray) -> jnp.ndarray:
-    """Fully path-compress ``parent`` so parent[v] is v's root for all v."""
+    """Fully path-compress ``parent`` so parent[v] is v's root for all v.
+
+    Traced under the device phase name ``mst.jump`` (DESIGN.md §4); a
+    jump inside a hooking wave keeps it, since the innermost scope wins.
+    """
 
     def cond(p):
         return jnp.any(p != p[p])

@@ -97,7 +97,7 @@ def unpack_results_mst(buckets: Sequence[PackedBucket],
 
     n = sum(len(b.indices) for b in buckets)
     out: List[MSTResult] = [None] * n  # type: ignore[list-item]
-    with _obs_phase("pack"):
+    with _obs_phase("trim"):
         # ONE device->host transfer for all buckets (not per bucket, and
         # not per lane per field) — at high lane counts the per-bucket
         # sync was a visible slice of batched throughput.

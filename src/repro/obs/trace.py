@@ -9,15 +9,26 @@ Three small pieces glue the engines to the registry (DESIGN.md §4):
     arrays (live edges, cumulative commits, lock waves, compaction scan
     bucket).
   * :func:`phase` / :func:`collect_phases` — a thread-local stack of
-    phase accumulators.  Host-side helpers deep inside the engines
-    (``rank_edges_host``, ``pack_padded``, ``unpack_results_mst``) wrap
-    themselves in ``phase("rank")`` / ``phase("pack")``; when no
-    collector is active (plain engine calls outside the solver) the hook
-    is a no-op costing one attribute lookup.
+    phase accumulators.  Host-side helpers deep inside the engines and
+    the service wrap themselves in ``phase(name)``: ``rank``
+    (``rank_edges_host``), ``pack`` (``pack_padded``), ``trim``
+    (``unpack_results_mst``), ``hash`` and ``cache`` (``MSTService``
+    submit and flush).  With annotations on, each phase is also a
+    profiler annotation ``mst.<name>``, on the device trace's clock.
+    When no collector is active and annotations are off (plain engine
+    calls outside the solver) the hook is a no-op costing one attribute
+    lookup.
   * :func:`annotate` — opt-in ``jax.profiler.TraceAnnotation`` so
-    Perfetto traces show named epochs (``boruvka_round``); off by
-    default, enabled via :func:`enable_annotations` or the
-    ``REPRO_OBS_ANNOTATE=1`` environment variable.
+    Perfetto traces show named epochs (``boruvka_round``) and dispatches
+    (``mst_solve:<engine>``); off by default, enabled via
+    :func:`enable_annotations` or the ``REPRO_OBS_ANNOTATE=1``
+    environment variable.
+
+The device side of the same trace is named in the engines:
+``core/engine.py`` traces each step of a Borůvka round under a
+``jax.named_scope`` (``mst.scan``, ``mst.hook``, ``mst.jump``,
+``mst.sort``, ``mst.compact``, ``mst.finish``), which lands in the
+compiled HLO's op metadata at no runtime cost.
 
 Phase accounting is *wall time on this thread*: nested collectors do not
 double-count because ``phase`` writes into the innermost collector only.
@@ -32,6 +43,7 @@ import time
 from typing import Dict, Iterator, List, Optional, Tuple
 
 _TLS = threading.local()
+PHASE_PREFIX = "mst."  # profiler annotation of phase(name): mst.<name>
 
 
 def _stack() -> List[Dict[str, float]]:
@@ -57,17 +69,34 @@ def collect_phases() -> Iterator[Dict[str, float]]:
 @contextlib.contextmanager
 def phase(name: str) -> Iterator[None]:
     """Accumulate this block's wall time under ``name`` in the innermost
-    active collector (no-op when none is active)."""
+    active collector.  With annotations on, the block is also the
+    profiler annotation ``mst.<name>``, whether or not a collector is
+    active.  A no-op when neither applies."""
     stack = _stack()
-    if not stack:
+    if not stack and not _ANNOTATE:
         yield
         return
-    acc = stack[-1]
+    acc = stack[-1] if stack else None
+    mark = None
+    if _ANNOTATE:
+        from jax.profiler import TraceAnnotation
+        mark = TraceAnnotation(PHASE_PREFIX + name)
+        mark.__enter__()
     t0 = time.perf_counter()
     try:
         yield
     finally:
-        acc[name] = acc.get(name, 0.0) + (time.perf_counter() - t0)
+        if acc is not None:
+            acc[name] = acc.get(name, 0.0) + (time.perf_counter() - t0)
+        if mark is not None:
+            mark.__exit__(None, None, None)
+
+
+def pack_time(phases: Dict[str, float]) -> float:
+    """Lane packing plus result trimming, in the unit of ``phases``: what
+    every pack counter reports (``SolveTrace.pack_us``,
+    ``mst_pack_latency_us``, ``mstserve_pack_latency_us``)."""
+    return phases.get("pack", 0.0) + phases.get("trim", 0.0)
 
 
 # -- profiler annotations ----------------------------------------------------
@@ -113,7 +142,8 @@ class SolveTrace:
         for packed dispatches).
       mst_edges: committed forest edges (summed over lanes).
       rank_us / pack_us / solve_us / total_us: wall-time split.  rank is
-        host edge ranking, pack is lane packing/unpacking (attributed
+        host edge ranking, pack is lane packing plus result trimming (the
+        ``pack`` and ``trim`` phases; attributed
         evenly across a ``solve_many`` call's buckets), solve is the
         remainder of the blocked dispatch.
       host_phases: every named host phase the dispatch collected, in
@@ -173,5 +203,5 @@ class SolveTrace:
         return d
 
 
-__all__ = ["SolveTrace", "phase", "collect_phases", "annotate",
+__all__ = ["SolveTrace", "phase", "collect_phases", "pack_time", "annotate",
            "enable_annotations", "annotations_enabled"]

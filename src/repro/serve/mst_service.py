@@ -43,7 +43,7 @@ from repro.obs.exporter import MetricsExporter
 from repro.obs.flight import FlightRecorder
 from repro.obs.metrics import BATCH_BUCKETS, MetricsRegistry
 from repro.obs.span import Span, SpanSampler, now_us, use_span
-from repro.obs.trace import collect_phases
+from repro.obs.trace import collect_phases, pack_time, phase
 
 
 @dataclass(frozen=True)
@@ -164,7 +164,12 @@ class ServiceStats:
         self.h_flush_batch = r.histogram("mstserve_flush_batch_size",
                                          buckets=BATCH_BUCKETS)
         self.h_flush_latency = r.histogram("mstserve_flush_latency_us")
+        # Host phases of a request (DESIGN.md §4): content hashing in
+        # submit; lane packing plus result trimming per flush (pack), and
+        # the trimming alone (trim).
+        self.h_hash = r.histogram("mstserve_hash_latency_us")
         self.h_pack = r.histogram("mstserve_pack_latency_us")
+        self.h_trim = r.histogram("mstserve_trim_latency_us")
         self.h_update_latency = r.histogram("mstserve_update_latency_us")
 
     # -- legacy int views ---------------------------------------------------
@@ -342,7 +347,11 @@ class MSTService:
         rid = self._next_id
         self._next_id += 1
         t_sub = now_us() if self.sampler.sample() else None
-        self._pending.append((rid, graph_key(g), g, t_sub))
+        t0 = time.perf_counter()
+        with phase("hash"):
+            key = graph_key(g)
+        self.stats.h_hash.observe((time.perf_counter() - t0) * 1e6)
+        self._pending.append((rid, key, g, t_sub))
         self.stats.c_submitted.inc()
         self.stats.g_queue_depth.set(len(self._pending))
         return rid
@@ -369,25 +378,25 @@ class MSTService:
 
         responses: Dict[int, MSTResponse] = {}
         misses: List[Tuple[int, str, Graph, Optional[float]]] = []
-        for rid, key, g, t_sub in pending:
-            hit = self._cache_get(self._cache, key)
-            if hit is not None:
-                self.stats.c_cache_hits.inc()
-                responses[rid] = MSTResponse(rid, hit.mst_mask, hit.parent,
-                                             hit.total_weight,
-                                             hit.num_components,
-                                             hit.num_rounds, cached=True)
-            else:
-                misses.append((rid, key, g, t_sub))
-        if record is not None:
-            record["probe_t1"] = now_us()
-
-        if misses:
+        with phase("cache"):
+            for rid, key, g, t_sub in pending:
+                hit = self._cache_get(self._cache, key)
+                if hit is not None:
+                    self.stats.c_cache_hits.inc()
+                    responses[rid] = MSTResponse(
+                        rid, hit.mst_mask, hit.parent, hit.total_weight,
+                        hit.num_components, hit.num_rounds, cached=True)
+                else:
+                    misses.append((rid, key, g, t_sub))
+            if record is not None:
+                record["probe_t1"] = now_us()
             # Intra-flush dedup: identical graphs (same content key) share
             # one engine lane; duplicates fan out from the first solve.
             unique: Dict[str, Tuple[int, str, Graph, Optional[float]]] = {}
             for m in misses:
                 unique.setdefault(m[1], m)
+
+        if misses:
             solve_list = list(unique.values())
             per_request = self._solve_batch(solve_list, record)
             by_key: Dict[str, MSTResponse] = {}
@@ -473,8 +482,9 @@ class MSTService:
         attached underneath via ``use_span``), ``scatter_t0``.
         """
         if self.solver.spec.supports_batched_lanes:
-            # The collector catches the "pack" phases (lane packing +
-            # result trimming) running outside the per-bucket dispatches.
+            # The collector catches the "pack" and "trim" phases (lane
+            # packing, result trimming) running outside the per-bucket
+            # dispatches.
             with collect_phases() as phases:
                 t0_us = now_us()
                 buckets = pack_graphs([g for _, _, g, _ in solve_list],
@@ -489,11 +499,10 @@ class MSTService:
                         self.stats.bucket_shapes.get(shape, 0)
                         + len(b.indices))
                     self.stats.c_engine_solves.inc(len(b.indices))
-                    t0 = time.perf_counter()
                     if record is None:
                         results.append(self.solver.solve_packed(b))
                     else:
-                        span = Span("solve", t0 * 1e6,
+                        span = Span("solve", now_us(),
                                     attrs={"shape": f"{shape[0]}x{shape[1]}",
                                            "lanes": len(b.indices),
                                            "shared": len(b.indices) > 1})
@@ -503,17 +512,13 @@ class MSTService:
                         by_key = record.setdefault("solve_by_key", {})
                         for i in b.indices:
                             by_key[solve_list[i][1]] = span
-                    # Per-bucket solve latency: the shape label stays
-                    # bounded by the pow2 bucketing.
-                    self.stats.registry.histogram(
-                        "mstserve_bucket_solve_latency_us",
-                        shape=f"{b.padded_edges}x{b.padded_nodes}").observe(
-                            (time.perf_counter() - t0) * 1e6)
                 if record is not None:
                     record["scatter_t0"] = now_us()
                 out = unpack_results(buckets, results)
-            if phases.get("pack"):
-                self.stats.h_pack.observe(phases["pack"] * 1e6)
+            if pack_time(phases):
+                self.stats.h_pack.observe(pack_time(phases) * 1e6)
+            if phases.get("trim"):
+                self.stats.h_trim.observe(phases["trim"] * 1e6)
             return out
         # Per-graph registry engines: one plan-cached dispatch per request.
         out = []
