@@ -5,7 +5,13 @@ Two flavours live here:
 * Device-side pointer jumping (Shiloach-Vishkin shortcut): ``parent <-
   parent[parent]`` until fixpoint fully path-compresses every vertex in
   O(log depth) vector steps.  After each Borůvka round we compress to
-  depth 1, so the per-round ``find`` is a single gather.
+  depth 1, so the per-round ``find`` is a single gather.  Each step makes
+  one gather: the loop carries ``(parent, changed)``, the body sets
+  ``changed`` from the gather it already made, and the loop test reads the
+  flag.  A test that gathered ``parent[parent]`` itself would cost a
+  second gather per step, and a third under ``jax.vmap``, whose
+  ``while_loop`` batching rule evaluates ``cond`` again inside the body to
+  pick which lanes advance.
 
 * ``HostUnionFind``: the scalar numpy structure every host-side replay
   path shares — the Kruskal oracle (``core/oracle.py``), single-linkage
@@ -74,28 +80,22 @@ class HostUnionFind:
 def pointer_jump(parent: jnp.ndarray) -> jnp.ndarray:
     """Fully path-compress ``parent`` so parent[v] is v's root for all v.
 
-    Traced under the device phase name ``mst.jump`` (DESIGN.md §4); a
-    jump inside a hooking wave keeps it, since the innermost scope wins.
+    One gather per step (module docstring); ``changed`` starts True, so
+    an already compressed ``parent`` costs one gather.  Traced under the
+    device phase name ``mst.jump`` (DESIGN.md §4); a jump inside a hooking
+    wave keeps it, since the innermost scope wins.
     """
 
-    def cond(p):
-        return jnp.any(p != p[p])
+    def cond(carry):
+        return carry[1]
 
-    def body(p):
-        return p[p]
+    def body(carry):
+        p, _ = carry
+        q = p[p]
+        return q, jnp.any(q != p)
 
-    return jax.lax.while_loop(cond, body, parent)
-
-
-def pointer_jump_fixed(parent: jnp.ndarray, num_steps: int) -> jnp.ndarray:
-    """Compress with a static number of doubling steps (scan-friendly).
-
-    ``num_steps = ceil(log2(V))`` guarantees full compression; useful inside
-    code that must avoid data-dependent trip counts (e.g. under vmap).
-    """
-    for _ in range(max(1, num_steps)):
-        parent = parent[parent]
-    return parent
+    out, _ = jax.lax.while_loop(cond, body, (parent, jnp.asarray(True)))
+    return out
 
 
 def is_root(parent: jnp.ndarray) -> jnp.ndarray:

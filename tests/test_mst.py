@@ -1,6 +1,7 @@
 """MST core: every variant vs the Kruskal oracle + property tests."""
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.core.mst import (minimum_spanning_forest, mst_optimized,
@@ -88,6 +89,68 @@ def test_pointer_jump_full_compression():
     c = pointer_jump(parent)
     assert (np.asarray(c) == np.asarray([3, 3, 3, 3, 4, 5, 5])).all()
     assert int(count_components(parent)) == 3
+
+
+def _numpy_roots(parent):
+    p = np.asarray(parent)
+    while True:
+        q = np.take_along_axis(p, p, axis=-1)
+        if np.array_equal(q, p):
+            return p
+        p = q
+
+
+def _random_forest(n, seed, reach):
+    """Parent array of a random forest: vertices in a random order, each
+    pointing ``reach`` or fewer places back (a root when it draws itself)."""
+    rng = np.random.default_rng(seed)
+    perm = rng.permutation(n)
+    pos = np.arange(n)
+    back = np.minimum(rng.integers(0, reach + 1, size=n), pos)
+    parent = np.empty(n, np.int32)
+    parent[perm] = perm[pos - back]
+    return parent
+
+
+_CHAIN = np.minimum(np.arange(1, 2 ** 12 + 1), 2 ** 12 - 1).astype(np.int32)
+
+
+@pytest.mark.parametrize("case", [
+    "identity", "star", "chain", "forest_shallow", "forest_deep",
+    "vmap_done_and_chain"])
+def test_pointer_jump_matches_numpy_fixpoint(case):
+    """Full compression equals the numpy fixpoint ``p = p[p]``, plain and
+    under vmap, where a lane that is already compressed rides along
+    unchanged while another lane still jumps."""
+    n = 2 ** 12
+    parents = {
+        "identity": np.arange(n, dtype=np.int32),
+        "star": np.zeros(n, np.int32),
+        "chain": _CHAIN,
+        "forest_shallow": _random_forest(n, seed=0, reach=n),
+        "forest_deep": _random_forest(n, seed=1, reach=3),
+        "vmap_done_and_chain": np.stack([
+            np.arange(n, dtype=np.int32), _CHAIN, np.zeros(n, np.int32),
+            _random_forest(n, seed=2, reach=2)]),
+    }
+    parent = parents[case]
+    jump = jax.vmap(pointer_jump) if parent.ndim == 2 else pointer_jump
+    got = np.asarray(jax.jit(jump)(jnp.asarray(parent)))
+    assert got.dtype == parent.dtype
+    np.testing.assert_array_equal(got, _numpy_roots(parent))
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_pointer_jump_one_gather_per_step(batched):
+    """The loop carries its convergence flag: the lowered program holds one
+    gather, plain and under vmap (whose while_loop batching rule would
+    evaluate a gathering ``cond`` again inside the body)."""
+    if batched:
+        jump, x = jax.vmap(pointer_jump), jnp.zeros((64, 16384), jnp.int32)
+    else:
+        jump, x = pointer_jump, jnp.arange(4096, dtype=jnp.int32)
+    hlo = jax.jit(jump).lower(x).as_text(dialect="hlo")
+    assert hlo.count(" gather(") == 1
 
 
 def test_coarsening_merges_and_pools():
