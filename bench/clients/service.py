@@ -31,7 +31,8 @@ class Client:
 
     def counters(self) -> dict:
         st = self.service.stats
-        return {"pack_us": st.h_pack.sum, "flushes": st.flushes}
+        return {"pack_us": st.h_pack.sum, "hash_us": st.h_hash.sum,
+                "trim_us": st.h_trim.sum, "flushes": st.flushes}
 
     def close(self) -> None:
         self.service.close()

@@ -35,7 +35,7 @@ from typing import Callable, Dict, List, NamedTuple, Optional
 
 import numpy as np
 
-from bench import graphgen, loadgen, reference, traces
+from bench import graphgen, loadgen, phases, reference, traces
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
@@ -169,7 +169,10 @@ def find_chips(chips: int):
 def enable_compile_cache(root: Path) -> str:
     """JAX's persistent compilation cache at a fixed path: where
     ``JAX_COMPILATION_CACHE_DIR`` says, else ``.jax_cache/`` in the
-    checkout.  Every program is written to it, however fast it compiled."""
+    checkout.  Every program is written to it, however fast it compiled.
+    The key includes the HLO metadata, so that a cached executable carries
+    the ``mst.*`` scopes of the code that runs (``bench/phases.py``), not
+    those of the commit that compiled it."""
     import jax
 
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
@@ -177,6 +180,7 @@ def enable_compile_cache(root: Path) -> str:
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path
 
 
@@ -288,13 +292,20 @@ def start_trace() -> str:
 
 
 def reduce_trace(trace_dir: str, platform: str, chips: int):
+    """The window's ``TraceSummary``, with the device time per engine
+    phase; prints the phase split on a ``phases:`` line."""
     t = time.perf_counter()
     try:
-        ops, host_spans = traces.read_events(traces.find_xplane(trace_dir),
-                                             platform)
+        xspace = Path(traces.find_xplane(trace_dir)).read_bytes()
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
+    ops, host_spans = traces.read_events(xspace, platform)
     summary = traces.summarize(ops, host_spans, chips)
+    split = phases.summarize(ops, host_spans, phases.module_phases(xspace),
+                             chips)
+    if summary is not None:
+        summary = summary._replace(phase_s=split.phase_s)
+        say("phases: " + json.dumps(split._asdict()))
     say(f"trace: ops={len(ops)} spans={len(host_spans)} "
         f"reduce_s={time.perf_counter() - t!r}")
     return summary

@@ -1,0 +1,7 @@
+"""Candidate search (``mst.scan``): device busy time per call, from the
+profiler trace."""
+from bench import readers
+
+
+def read(run):
+    return readers.phase_ms_per_call(run, "scan")

@@ -1,55 +1,49 @@
-"""Device time per engine phase, and idle time per host phase of the
-program, from the profiler trace that ``traces.py`` reduces.
+"""Device time per engine phase, from the ops that ``traces.read_events``
+reads out of the profiler trace.
 
 The engines trace each step of a Borůvka round under a ``jax.named_scope``
 (``mst.scan``, ``mst.hook``, ``mst.jump``, ``mst.sort``, ``mst.compact``,
-``mst.finish``).  XLA keeps the scope in the ``op_name`` of each
-instruction's metadata, and the trace file carries the compiled HLO of
-every module it ran in its ``/host:metadata`` plane.  This module reads
-that HLO, keyed by module and instruction name, and so gives every device
-op its innermost ``mst.*`` scope: one path on the TPU and on the CPU the
-tests record on, whatever numbers XLA gave the ops in this compile.  A
-fusion the compiler made without metadata takes the phase fused into it.
-An executable loaded from JAX's persistent cache keeps the metadata it was
-compiled with unless ``jax_compilation_cache_include_metadata_in_key`` is
-on, so a run that loads another commit's executable reads as ``other``.
+``mst.finish``; any other ``mst.<word>`` scope is a phase of that name
+too, so a new step of the program needs no change here).  XLA keeps the
+scope in the ``op_name`` of each instruction's metadata, and the trace
+file carries the compiled HLO of every module it ran in its
+``/host:metadata`` plane.  This module reads that HLO, keyed by module and
+instruction name, and so gives every device op its innermost ``mst.*``
+scope (``traces.Op.module`` names the module an op ran in): one path on
+the TPU and on the CPU the tests record on, whatever numbers XLA gave the
+ops in this compile.  A fusion the compiler made without metadata takes
+the phase fused into it.  An executable loaded from JAX's persistent
+cache keeps the metadata it was compiled with unless
+``jax_compilation_cache_include_metadata_in_key`` is on, as the harness
+sets it, so that no run reads another commit's scopes.
 
 Busy time is cut into pieces over which the set of running ops does not
 change; each piece goes to the phase of the innermost op running (the
 one that started last), so the phases, ``other`` included, sum to the
-busy time of ``traces.summarize``.  The host phases the program marks
-(``mst.rank``, ``mst.pack``, ``mst.trim``, ``mst.hash``, ``mst.cache``)
-join the benchmark's spans in the labels of the idle gaps.
+busy time of ``traces.summarize``.  The six phases above are always keys
+of ``phase_s``.  The host phases the program marks (``mst.rank``,
+``mst.pack``, ``mst.trim``, ``mst.hash``, ``mst.cache``) are among the
+spans ``traces`` reads, and label the idle gaps.
 """
 from __future__ import annotations
 
 import heapq
 import re
-from typing import Dict, Iterator, List, NamedTuple, Optional, Tuple
+from typing import (Dict, Iterator, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 from bench import traces
 
 PHASES = ("scan", "hook", "jump", "sort", "compact", "finish")
 OTHER = "other"
-SPAN_PREFIXES = traces.SPAN_PREFIXES + ("mst.",)
 METADATA_PLANE = "/host:metadata"
 HLO_PROTO_STAT = "Hlo Proto"
-_SCOPE = re.compile(r"\bmst\.(" + "|".join(PHASES) + r")\b")
-
-
-class DeviceOp(NamedTuple):
-    device: int
-    name: str
-    module: Optional[str]   # "<hlo module>(<program id>)" when known
-    start_ns: float
-    end_ns: float
+_SCOPE = re.compile(r"\bmst\.(\w+)")
 
 
 class PhaseSummary(NamedTuple):
     phase_s: Dict[str, float]            # busy seconds per phase, per chip
-    busy_s: float                        # their sum
-    device_ops: List[Tuple[str, str, float]]  # (op, phase, self seconds)
-    idle_gaps: List[Tuple[str, float]]   # labelled with mst.* phases too
+    device_ops: List[Tuple[str, str, float]]  # (op, phase, seconds)
     unmapped_modules: List[str]          # modules with no HLO in the trace
 
 
@@ -206,70 +200,6 @@ def module_phases(xspace: bytes) -> Dict[str, Dict[str, str]]:
     return out
 
 
-# -- events ------------------------------------------------------------------
-
-def _module_key(stats: Dict[str, object]) -> Optional[str]:
-    module, program = stats.get("hlo_module"), stats.get("program_id")
-    if module is None or program is None:
-        return None
-    return f"{module}({program})"
-
-
-def read_events(path: str, platform: str
-                ) -> Tuple[List[DeviceOp], List[traces.Span]]:
-    """The device operations, with their module, and the host spans of
-    the benchmark and of the program, ``mst.*`` phases included."""
-    from jax.profiler import ProfileData
-
-    profile = ProfileData.from_file(path)
-    ops: List[DeviceOp] = []
-    spans: List[traces.Span] = []
-    for plane in profile.planes:
-        if plane.name.startswith("/device:TPU:") and platform == "tpu":
-            device = int(plane.name.rsplit(":", 1)[1])
-            lines = {line.name: line for line in plane.lines}
-            modules = [(e.start_ns, e.end_ns, e.name) for e in
-                       lines["XLA Modules"].events] \
-                if "XLA Modules" in lines else []
-            if "XLA Ops" in lines:
-                ops.extend(_tpu_ops(device, lines["XLA Ops"].events,
-                                    modules))
-        elif plane.name == "/host:CPU":
-            for line in plane.lines:
-                cpu_ops = platform == "cpu" and line.name.startswith(
-                    "tf_XLAPjRtCpuClient")
-                for e in line.events:
-                    if e.name.startswith(SPAN_PREFIXES):
-                        spans.append(traces.Span(e.name, e.start_ns,
-                                                 e.end_ns))
-                    elif cpu_ops and not e.name.startswith("end:"):
-                        stats = dict(e.stats)
-                        if "hlo_op" in stats:
-                            ops.append(DeviceOp(0, e.name,
-                                                _module_key(stats),
-                                                e.start_ns, e.end_ns))
-    return ops, spans
-
-
-def _tpu_ops(device: int, events, modules) -> List[DeviceOp]:
-    """A TPU op carries no module of its own: it belongs to the ``XLA
-    Modules`` event that encloses it in time."""
-    out: List[DeviceOp] = []
-    modules = sorted(modules)
-    j = 0
-    for e in sorted(events, key=lambda e: e.start_ns):
-        key = None
-        if modules:
-            while j + 1 < len(modules) and modules[j + 1][0] <= e.start_ns:
-                j += 1
-            s, t, name = modules[j]
-            if s <= e.start_ns < t:
-                key = name
-        out.append(DeviceOp(device, traces.op_name(e.name), key,
-                            e.start_ns, e.end_ns))
-    return out
-
-
 # -- reduction ---------------------------------------------------------------
 
 def _innermost_time(ops: List[Tuple[float, float]]) -> Dict[int, float]:
@@ -301,21 +231,23 @@ def _innermost_time(ops: List[Tuple[float, float]]) -> Dict[int, float]:
     return out
 
 
-def summarize(ops: List[DeviceOp], spans: List[traces.Span],
+def summarize(ops: Sequence[traces.Op], spans: Sequence[traces.Span],
               scopes: Dict[str, Dict[str, str]],
               chips: int) -> Optional[PhaseSummary]:
-    """Phase seconds inside the ``bench.window`` span; None without one."""
-    windows = [s for s in spans if s.name == traces.WINDOW_SPAN]
-    if len(windows) != 1:
+    """Phase seconds inside the ``bench.window`` span, from the ops and
+    spans of ``traces.read_events`` and the ``scopes`` of
+    ``module_phases``; None without a window."""
+    bounds = traces.window(spans)
+    if bounds is None:
         return None
-    lo, hi = windows[0].start_ns, windows[0].end_ns
+    lo, hi = bounds
 
-    def phase(op: DeviceOp) -> str:
+    def phase(op: traces.Op) -> str:
         return scopes.get(op.module, {}).get(op.name, OTHER)
 
     phase_ns = {p: 0.0 for p in PHASES + (OTHER,)}
     op_ns: Dict[Tuple[str, str], float] = {}
-    by_device: Dict[int, List[DeviceOp]] = {}
+    by_device: Dict[int, List[traces.Op]] = {}
     for op in ops:
         s, e = max(op.start_ns, lo), min(op.end_ns, hi)
         if e > s:
@@ -326,27 +258,13 @@ def summarize(ops: List[DeviceOp], spans: List[traces.Span],
         for i, ns in timed.items():
             op = dev_ops[i]
             p = phase(op)
-            phase_ns[p] += ns
+            phase_ns[p] = phase_ns.get(p, 0.0) + ns
             op_ns[(op.name, p)] = op_ns.get((op.name, p), 0.0) + ns
     per_chip = 1e9 * max(chips, 1)
     top = sorted(op_ns.items(), key=lambda kv: -kv[1])[:traces.TOP]
-    base = traces.summarize([traces.Op(o.device, o.name, o.start_ns,
-                                       o.end_ns) for o in ops],
-                            spans, chips)
     unmapped = sorted({o.module for o in ops
                        if o.module is not None and o.module not in scopes})
     return PhaseSummary(
         phase_s={p: ns / per_chip for p, ns in phase_ns.items()},
-        busy_s=sum(phase_ns.values()) / per_chip,
         device_ops=[(name, p, ns / per_chip) for (name, p), ns in top],
-        idle_gaps=base.idle_gaps if base is not None else [],
         unmapped_modules=unmapped)
-
-
-def summarize_file(path: str, platform: str,
-                   chips: int) -> Optional[PhaseSummary]:
-    """``summarize`` of one ``.xplane.pb``."""
-    ops, spans = read_events(path, platform)
-    with open(path, "rb") as f:
-        scopes = module_phases(f.read())
-    return summarize(ops, spans, scopes, chips)

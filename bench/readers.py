@@ -60,6 +60,17 @@ def device_ms_per_call(run):
     return 1e3 * run.trace.busy_s / len(run.calls)
 
 
+def phase_ms_per_call(run, phase: str):
+    """Device busy time in one engine phase (``bench/phases.py``) per
+    call; None where the trace has no time in that phase."""
+    if run.trace is None or not run.calls or not run.trace.phase_s:
+        return None
+    seconds = run.trace.phase_s.get(phase, 0.0)
+    if seconds <= 0:
+        return None
+    return 1e3 * seconds / len(run.calls)
+
+
 def idle_share(run):
     if run.trace is None or run.trace.window_s <= 0:
         return None

@@ -6,9 +6,10 @@ The cells are the ``workloads`` of ``BENCHMARK.json``.  The last line of
 standard output is one JSON object: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the cell's end-to-end metrics, or with ``--trace 1`` its
 per-layer metrics), ``device``, with ``--trace 1`` a ``breakdown``, and
-last the numbers compared with the reference beside their limits.
-Without a TPU, or with fewer chips than the cell asks for, it prints no
-result and exits 3.
+last the numbers compared with the reference beside their limits.  A
+traced run also prints, before it, a ``phases:`` line: the window's device
+time per engine phase (``bench/phases.py``).  Without a TPU, or with
+fewer chips than the cell asks for, it prints no result and exits 3.
 
 ``--control bfloat16`` puts the plain reference, computed on weights
 rounded to bfloat16, in the program's place; such a run has to come out

@@ -7,7 +7,9 @@ busy time ``traces.summarize`` reports, and idle gaps labelled with the
 program's host phases, while ``traces.summarize`` itself reads the same
 numbers with or without those phase spans.
 """
+import os
 import tempfile
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -43,6 +45,14 @@ def recorded():
     return traces.find_xplane(d)
 
 
+def read(path):
+    """The ops and spans of one trace file, and its phase split."""
+    xspace = Path(path).read_bytes()
+    ops, spans = traces.read_events(xspace, "cpu")
+    return ops, spans, phases.summarize(ops, spans,
+                                        phases.module_phases(xspace), 1)
+
+
 @pytest.mark.parametrize("op_name,phase", [
     ("jit(_msf_jit)/while/body/mst.scan/gather", "scan"),
     ("jit(f)/mst.hook/vmap(mst.jump)/while/body/gather", "jump"),
@@ -50,7 +60,10 @@ def recorded():
     ("jit(f)/mst.finish/mst.jump/while", "jump"),
     ("jit(f)/mst.compact/cumsum", "compact"),
     ("jit(f)/reduce_sum", phases.OTHER),
-    ("jit(f)/mst.scanner/add", phases.OTHER),
+    ("jit(f)/mst.scanner/add", "scanner"),
+    ("jit(f)/mst.lock/mst.jump/gather", "jump"),
+    ("jit(f)/vmap(mst.lock)/scatter", "lock"),
+    ("jit(f)/mst_solve/add", phases.OTHER),
     ("", phases.OTHER),
 ])
 def test_phase_is_the_innermost_mst_scope(op_name, phase):
@@ -113,9 +126,9 @@ def test_innermost_op_takes_each_piece_of_busy_time():
 
 
 def test_every_op_of_the_trace_finds_its_module(recorded):
-    ops, _ = phases.read_events(recorded, "cpu")
-    with open(recorded, "rb") as f:
-        scopes = phases.module_phases(f.read())
+    xspace = Path(recorded).read_bytes()
+    ops, _ = traces.read_events(xspace, "cpu")
+    scopes = phases.module_phases(xspace)
     assert ops and all(o.module in scopes for o in ops)
     rounds = {o.module for o in ops
               if o.module.startswith("jit__one_round_jit")}
@@ -125,43 +138,100 @@ def test_every_op_of_the_trace_finds_its_module(recorded):
 
 
 def test_phases_sum_to_busy_time(recorded):
-    got = phases.summarize_file(recorded, "cpu", 1)
-    ops, spans = traces.read_events(recorded, "cpu")
+    ops, spans, got = read(recorded)
     base = traces.summarize(ops, spans, 1)
     assert set(got.phase_s) == set(phases.PHASES) | {phases.OTHER}
-    assert sum(got.phase_s.values()) == pytest.approx(got.busy_s)
-    assert got.busy_s == pytest.approx(base.busy_s, rel=1e-3)
+    busy_s = sum(got.phase_s.values())
+    assert busy_s == pytest.approx(base.busy_s, rel=1e-3)
     for p in ("scan", "hook", "jump"):
         assert got.phase_s[p] > 0, got.phase_s
-    named = got.busy_s - got.phase_s[phases.OTHER]
-    assert named >= 0.5 * got.busy_s
+    named = busy_s - got.phase_s[phases.OTHER]
+    assert named >= 0.5 * busy_s
     assert got.unmapped_modules == []
     assert all(p in phases.PHASES + (phases.OTHER,)
                for _, p, _ in got.device_ops)
 
 
 def test_idle_gaps_carry_the_program_host_phases(recorded):
-    got = phases.summarize_file(recorded, "cpu", 1)
+    ops, spans, _ = read(recorded)
+    got = traces.summarize(ops, spans, 1)
     labels = dict(got.idle_gaps)
     assert "bench.solve>mst.rank" in labels
-    ops, spans = traces.read_events(recorded, "cpu")
-    base = traces.summarize(ops, spans, 1)
     assert sum(labels.values()) == pytest.approx(
-        sum(dict(base.idle_gaps).values()), rel=1e-6)
+        got.window_s - got.busy_s, rel=1e-6)
 
 
 def test_trace_summary_is_blind_to_the_phase_spans(recorded):
     """The fields the accepted metrics read come out the same whether or
-    not the program marks its host phases in the trace."""
-    ops, spans = traces.read_events(recorded, "cpu")
-    _, all_spans = phases.read_events(recorded, "cpu")
-    assert any(s.name.startswith("mst.") for s in all_spans)
-    plain = [s for s in all_spans if not s.name.startswith("mst.")]
-    assert sorted(plain) == sorted(spans)
-    assert traces.summarize(ops, spans, 1) == traces.summarize(ops, plain, 1)
+    not the program marks its host phases in the trace; the phases only
+    relabel the idle gaps."""
+    ops, spans, _ = read(recorded)
+    assert any(s.name.startswith("mst.") for s in spans)
+    plain = [s for s in spans if not s.name.startswith("mst.")]
+    marked, bare = (traces.summarize(ops, spans, 1),
+                    traces.summarize(ops, plain, 1))
+    assert (marked.busy_s, marked.window_s, marked.device_ops) == \
+        (bare.busy_s, bare.window_s, bare.device_ops)
+    assert sum(dict(marked.idle_gaps).values()) == pytest.approx(
+        sum(dict(bare.idle_gaps).values()), rel=1e-9)
+    assert "bench.solve>mst.rank" in dict(marked.idle_gaps)
+    assert not any("mst." in k for k, _ in bare.idle_gaps)
+
+
+def test_reduced_trace_reads_what_it_read_before_the_phases(recorded,
+                                                            tmp_path):
+    """``harness.reduce_trace`` gives the busy time, window, top ops and
+    idle share of the trace reduction without the program's phases, and
+    adds the phase split of ``phases.summarize``."""
+    import shutil
+
+    from bench import harness
+
+    ops, spans, split = read(recorded)
+    before = traces.summarize(
+        ops, [s for s in spans if not s.name.startswith("mst.")], 1)
+    copy = tmp_path / "trace"
+    shutil.copytree(os.path.dirname(recorded), copy)
+    after = harness.reduce_trace(str(copy), "cpu", 1)
+    assert not copy.exists()
+    assert (after.busy_s, after.window_s, after.device_ops) == \
+        (before.busy_s, before.window_s, before.device_ops)
+    assert 1 - after.busy_s / after.window_s == \
+        1 - before.busy_s / before.window_s
+    assert after.phase_s == split.phase_s
+    assert before.phase_s is None
+
+
+def test_a_new_scope_is_a_phase_of_its_own(tmp_path):
+    """A device scope ``mst.<word>`` that this module does not name reads
+    into ``phase_s`` under that word, beside the known phases at 0."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def f(x):
+        with jax.named_scope("mst.probe"):
+            y = jnp.sort(x) * 2
+        return y + 1
+
+    x = jnp.arange(100_000, dtype=jnp.float32)[::-1]
+    f(x).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation(traces.WINDOW_SPAN):
+            for _ in range(3):
+                f(x).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    ops, spans, got = read(traces.find_xplane(str(tmp_path)))
+    assert got.phase_s["probe"] > 0
+    for p in phases.PHASES:
+        assert got.phase_s[p] == 0.0
+    assert sum(got.phase_s.values()) == pytest.approx(
+        traces.summarize(ops, spans, 1).busy_s)
 
 
 def test_no_window_no_summary(recorded):
-    ops, spans = phases.read_events(recorded, "cpu")
+    ops, spans, _ = read(recorded)
     outside = [s for s in spans if s.name != traces.WINDOW_SPAN]
     assert phases.summarize(ops, outside, {}, 1) is None

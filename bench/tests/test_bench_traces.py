@@ -1,5 +1,6 @@
 """Trace reduction, the table of peaks and the one-pass byte count."""
 import time
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -80,7 +81,8 @@ def test_reduction_of_a_trace_recorded_on_the_cpu(tmp_path):
                 time.sleep(0.02)
     finally:
         jax.profiler.stop_trace()
-    ops, spans = traces.read_events(traces.find_xplane(str(tmp_path)), "cpu")
+    xspace = Path(traces.find_xplane(str(tmp_path))).read_bytes()
+    ops, spans = traces.read_events(xspace, "cpu")
     assert [s.name for s in spans].count("bench.solve") == 3
     assert any(o.name.startswith("sort") for o in ops)
     s = traces.summarize(ops, spans, chips=1)

@@ -4,9 +4,12 @@ The traced run writes one ``.xplane.pb``.  From it this module takes
 
 * the device's operations: on a TPU the events of the ``XLA Ops`` line of
   each ``/device:TPU:<n>`` plane; on the CPU, which the tests record on,
-  the events of the host's XLA threads that carry an ``hlo_op`` stat;
+  the events of the host's XLA threads that carry an ``hlo_op`` stat: the
+  client's thread, and the worker threads that run the body of a loop;
+  each with the module it ran in, which ``phases.py`` reads the phase of;
 * the host spans of the benchmark (``bench.*``) and of the program
-  (``mst_solve:<engine>``), written by ``jax.profiler.TraceAnnotation``.
+  (``mst_solve:<engine>``, and its host phases ``mst.*``), written by
+  ``jax.profiler.TraceAnnotation``.
 
 Busy time is the union of the operations' intervals inside the
 ``bench.window`` span, averaged over the chips the cell uses.  The idle
@@ -21,7 +24,8 @@ import os
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 WINDOW_SPAN = "bench.window"
-SPAN_PREFIXES = ("bench.", "mst_solve:")
+SPAN_PREFIXES = ("bench.", "mst_solve:", "mst.")
+CPU_OP_LINES = ("tf_XLAPjRtCpuClient", "tf_XLAEigen")
 IDLE_LABEL = "no span open"
 TOP = 10
 
@@ -31,6 +35,7 @@ class Op(NamedTuple):
     name: str
     start_ns: float
     end_ns: float
+    module: Optional[str] = None  # "<hlo module>(<program id>)" when known
 
 
 class Span(NamedTuple):
@@ -44,6 +49,16 @@ class TraceSummary(NamedTuple):
     window_s: float        # length of the bench.window span
     device_ops: List[Tuple[str, float]]   # top ops by self seconds per chip
     idle_gaps: List[Tuple[str, float]]    # idle seconds per host label
+    # busy seconds per device phase, per chip (``bench/phases.py``)
+    phase_s: Optional[Dict[str, float]] = None
+
+
+def window(spans: Sequence[Span]) -> Optional[Tuple[float, float]]:
+    """Start and end of the one ``bench.window`` span; None without it."""
+    windows = [s for s in spans if s.name == WINDOW_SPAN]
+    if len(windows) != 1:
+        return None
+    return windows[0].start_ns, windows[0].end_ns
 
 
 def find_xplane(log_dir: str) -> str:
@@ -55,31 +70,63 @@ def find_xplane(log_dir: str) -> str:
     return found[0]
 
 
-def read_events(path: str, platform: str) -> Tuple[List[Op], List[Span]]:
-    """The device operations and the host spans of one trace file."""
+def read_events(xspace: bytes, platform: str
+                ) -> Tuple[List[Op], List[Span]]:
+    """The device operations, each with its module, and the host spans of
+    one trace: the bytes of its ``.xplane.pb``."""
     from jax.profiler import ProfileData
 
-    profile = ProfileData.from_file(path)
+    profile = ProfileData.from_serialized_xspace(xspace)
     ops: List[Op] = []
     spans: List[Span] = []
     for plane in profile.planes:
         if plane.name.startswith("/device:TPU:") and platform == "tpu":
             device = int(plane.name.rsplit(":", 1)[1])
-            for line in plane.lines:
-                if line.name == "XLA Ops":
-                    ops.extend(Op(device, op_name(e.name), e.start_ns,
-                                  e.end_ns) for e in line.events)
+            lines = {line.name: line for line in plane.lines}
+            modules = [(e.start_ns, e.end_ns, e.name) for e in
+                       lines["XLA Modules"].events] \
+                if "XLA Modules" in lines else []
+            if "XLA Ops" in lines:
+                ops.extend(_tpu_ops(device, lines["XLA Ops"].events,
+                                    modules))
         elif plane.name == "/host:CPU":
             for line in plane.lines:
                 cpu_ops = platform == "cpu" and line.name.startswith(
-                    "tf_XLAPjRtCpuClient")
+                    CPU_OP_LINES)
                 for e in line.events:
                     if e.name.startswith(SPAN_PREFIXES):
                         spans.append(Span(e.name, e.start_ns, e.end_ns))
-                    elif cpu_ops and not e.name.startswith("end:") and \
-                            "hlo_op" in dict(e.stats):
-                        ops.append(Op(0, e.name, e.start_ns, e.end_ns))
+                    elif cpu_ops and not e.name.startswith("end:"):
+                        stats = dict(e.stats)
+                        if "hlo_op" in stats:
+                            ops.append(Op(0, e.name, e.start_ns, e.end_ns,
+                                          _module_key(stats)))
     return ops, spans
+
+
+def _module_key(stats: Dict[str, object]) -> Optional[str]:
+    module, program = stats.get("hlo_module"), stats.get("program_id")
+    if module is None or program is None:
+        return None
+    return f"{module}({program})"
+
+
+def _tpu_ops(device: int, events, modules) -> List[Op]:
+    """A TPU op carries no module of its own: it belongs to the ``XLA
+    Modules`` event that encloses it in time."""
+    out: List[Op] = []
+    modules = sorted(modules)
+    j = 0
+    for e in sorted(events, key=lambda e: e.start_ns):
+        key = None
+        if modules:
+            while j + 1 < len(modules) and modules[j + 1][0] <= e.start_ns:
+                j += 1
+            s, t, name = modules[j]
+            if s <= e.start_ns < t:
+                key = name
+        out.append(Op(device, op_name(e.name), e.start_ns, e.end_ns, key))
+    return out
 
 
 def op_name(hlo: str) -> str:
@@ -154,10 +201,10 @@ def label_timeline(spans: Sequence[Span], lo: float,
 def summarize(ops: Sequence[Op], spans: Sequence[Span],
               chips: int) -> Optional[TraceSummary]:
     """Busy and idle time inside the window; None without a window span."""
-    windows = [s for s in spans if s.name == WINDOW_SPAN]
-    if len(windows) != 1:
+    bounds = window(spans)
+    if bounds is None:
         return None
-    lo, hi = windows[0].start_ns, windows[0].end_ns
+    lo, hi = bounds
     inner = [s for s in spans if s.name != WINDOW_SPAN]
 
     by_device: Dict[int, List[Tuple[float, float]]] = {}
