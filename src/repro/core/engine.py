@@ -36,11 +36,11 @@ Every block is traced under a device phase name (``jax.named_scope``,
 metadata only), so a profiler trace maps each XLA op to a step of the
 round whatever the engine (DESIGN.md §4): ``mst.scan`` (endpoint-label
 gathers, covered mask, candidate minima: the E-sized work), ``mst.hook``
-(decode, hooking, commit: the V-sized work), ``mst.jump`` (pointer
-jumping), ``mst.sort`` (in-jit edge ranking), ``mst.compact`` (the
-compaction and contraction epochs around the rounds) and ``mst.finish``
-(commit flush, result).  The innermost scope wins, so a round inside an
-epoch keeps its own names.
+(decode, CAS hooking: V-sized), ``mst.lock`` (the lock variant's waves),
+``mst.jump`` (pointer jumping), ``mst.sort`` (in-jit edge ranking),
+``mst.compact`` (compaction and contraction epochs), ``mst.finish``
+(commit flush, result).  The innermost scope wins: a round inside an
+epoch keeps its names, a jump inside a lock wave reads ``mst.jump``.
 """
 from __future__ import annotations
 
@@ -875,10 +875,10 @@ def partner_components(parent, has, end_u, end_v):
     return other, iota
 
 
-@jax.named_scope("mst.hook")
 def commit_edges(mst_mask, cand_edge, commit):
     """Scatter-commit candidate edges; non-committers scatter out of bounds
-    (dropped), mirroring 'Add edge minimum[v] to the set M' under guard."""
+    (dropped), mirroring 'Add edge minimum[v] to the set M' under guard.
+    Unscoped: it reads as its caller's phase (``mst.hook``, ``mst.lock``)."""
     e = mst_mask.shape[0]
     idx = jnp.where(commit, cand_edge, e)  # e == out-of-bounds -> dropped
     return mst_mask.at[idx].set(True, mode="drop")
@@ -911,7 +911,7 @@ def hook_cas(parent, has, cand_edge, other, iota):
     return new_parent, commit
 
 
-@jax.named_scope("mst.hook")
+@jax.named_scope("mst.lock")
 def hook_lock_waves(parent, mst_mask, has, cand_edge, end_u, end_v,
                     *, max_waves: int, commit_fn=commit_edges):
     """Lock-variant hooking (paper §2.2.1), as propose-verify *retry waves*.

@@ -26,9 +26,9 @@ Three small pieces glue the engines to the registry (DESIGN.md §4):
 
 The device side of the same trace is named in the engines:
 ``core/engine.py`` traces each step of a Borůvka round under a
-``jax.named_scope`` (``mst.scan``, ``mst.hook``, ``mst.jump``,
-``mst.sort``, ``mst.compact``, ``mst.finish``), which lands in the
-compiled HLO's op metadata at no runtime cost.
+``jax.named_scope`` (``mst.scan``, ``mst.hook``, ``mst.lock``,
+``mst.jump``, ``mst.sort``, ``mst.compact``, ``mst.finish``), which lands
+in the compiled HLO's op metadata at no runtime cost.
 
 Phase accounting is *wall time on this thread*: nested collectors do not
 double-count because ``phase`` writes into the innermost collector only.

@@ -64,10 +64,10 @@ def _batched_hlo(variant: str, compaction: int) -> str:
 
 @pytest.mark.parametrize("engine,variant,compaction,extra", [
     ("single", "cas", 0, ()),
-    ("single", "lock", 0, ()),
+    ("single", "lock", 0, ("mst.lock",)),
     ("single", "cas", 2, ("mst.compact",)),
     ("batched", "cas", 0, ("mst.sort",)),
-    ("batched", "lock", 0, ("mst.sort",)),
+    ("batched", "lock", 0, ("mst.sort", "mst.lock")),
 ])
 def test_compiled_engine_names_its_device_phases(engine, variant,
                                                  compaction, extra):
@@ -76,6 +76,9 @@ def test_compiled_engine_names_its_device_phases(engine, variant,
     found = _scopes(hlo)
     for name in ROUND_SCOPES + extra:
         assert name in found, (name, sorted(found))
+    # Lock waves have a phase of their own; the CAS programs have none.
+    if variant == "cas":
+        assert "mst.lock" not in found, sorted(found)
 
 
 def _trace_event_names(fn) -> list:
