@@ -95,9 +95,9 @@ def rank_edges_host(weight) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
     Bit-identical ranks/order to the jnp version (both are stable ascending
     sorts, so ties break by edge id either way) but ~5-10x faster than the
-    XLA CPU sort — a fixed per-solve cost worth dodging for every engine
-    whose rank is computed at the host level (single, sequential,
-    distributed, sharded; the batched engine ranks in-jit under vmap).
+    XLA CPU sort.  The single engine ranks here on the CPU alone (it ranks
+    in-jit on an accelerator: ``mst._host_ranking``); the sequential,
+    distributed, sharded and spmm engines everywhere; batched, never.
     """
     with _obs_phase("rank"):
         w = np.asarray(weight)

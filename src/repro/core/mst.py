@@ -92,9 +92,12 @@ def minimum_spanning_forest(graph: Graph, *, num_nodes: Optional[int] = None,
                             contraction: bool = False) -> MSTResult:
     """Full Borůvka MSF as a single jitted ``lax.while_loop``.
 
-    The (weight, edge_id) rank is computed host-side (numpy stable
-    argsort — the XLA CPU sort is several times slower and was the largest
-    fixed per-solve cost); everything after is one jitted call.
+    Where the solve runs on an accelerator, the (weight, edge_id) rank is
+    computed inside the jitted program (``rank_edges``, device phase
+    ``mst.sort``), so the device never waits on the host for it.  On the
+    CPU it is computed host-side by ``rank_edges_host`` (numpy's stable
+    argsort is several times faster than XLA's CPU sort there).  Both are
+    the same stable ascending sort: the forest is the same bit for bit.
 
     Args:
       graph: edge-list graph (static shapes), preferably sized
@@ -130,8 +133,10 @@ def minimum_spanning_forest(graph: Graph, *, num_nodes: Optional[int] = None,
     if contraction and not compaction:
         raise ValueError("contraction requires compaction > 0 "
                          "(contraction happens at epoch boundaries)")
-    rank, order = rank_edges_host(graph.weight)
+    rank, order = _host_ranking(graph.weight)
     if contraction:
+        if rank is None:
+            rank, order = _rank_edges_jit(graph.weight)
         return _contracted_host_loop(
             graph, rank, order, variant=variant,
             max_lock_waves=max_lock_waves, compaction=compaction,
@@ -140,6 +145,28 @@ def minimum_spanning_forest(graph: Graph, *, num_nodes: Optional[int] = None,
                     variant=variant, track_covered=track_covered,
                     max_lock_waves=max_lock_waves, compaction=compaction,
                     compaction_kernel=compaction_kernel)
+
+
+def _platform() -> str:
+    """The platform the solve runs on (``cpu``, ``tpu``, ``gpu``)."""
+    return jax.default_backend()
+
+
+def _host_ranking(weight):
+    """``rank_edges_host(weight)`` where the host rank pays, else
+    ``(None, None)``: the engine then ranks inside its jitted program.
+
+    numpy's stable argsort beats XLA's CPU sort several times over, so
+    the CPU keeps the host rank.  On an accelerator the host rank leaves
+    the device idle for the whole sort, and copies rank and order to it
+    besides; there the device sorts, in the same program as the rounds.
+    """
+    if _platform() == "cpu":
+        return rank_edges_host(weight)
+    return None, None
+
+
+_rank_edges_jit = jax.jit(rank_edges)
 
 
 def _bucket_cover(sizes, count: int) -> int:
@@ -232,6 +259,8 @@ def _contracted_host_loop(graph: Graph, rank, order, *, variant: str,
 def _msf_jit(graph: Graph, rank, order, *, num_nodes: int, variant: str,
              track_covered: bool, max_lock_waves: int, compaction: int,
              compaction_kernel: bool) -> MSTResult:
+    if rank is None:  # ranked here, on the device (``_host_ranking``)
+        rank, order = rank_edges(graph.weight)
     e = graph.num_edges
     init = init_state(num_nodes, e, e, commit_slots=variant == "cas")
 
